@@ -42,8 +42,7 @@ def run(datasets=("rcv1",), steps: int = 24, lam: float = 20.0):
         if rec.ell_width is not None:
             winner = tiered_from_padded(pcsc, rec.ell_width)
             parity = probe_parity(pcsr, pcsc, winner, prob.y,
-                                  loss="logistic", interpret=True,
-                                  steps=steps, lam=lam)
+                                  loss="logistic", steps=steps, lam=lam)
         else:
             parity = True            # flat layout won: nothing to compare
         row = {

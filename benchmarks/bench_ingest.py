@@ -66,14 +66,14 @@ def run(datasets=("rcv1_like", "url_small_like"), steps: int = 40,
             # steady-state work, not first-call tracing ---------------------
             pcsr, _ = as_padded(X)
             _block(fw_setup_jit(pcsr, jnp.asarray(y, jnp.float32),
-                                loss=loss, interpret=cfg.interpret))
+                                loss=loss))
 
             # ---- in-memory baseline: what every solve re-pays without the
             # store (padding coercion + the O(nnz) setup spmv sweep) --------
             t0 = time.time()
             pcsr, _ = as_padded(X)
             setup = fw_setup_jit(pcsr, jnp.asarray(y, jnp.float32),
-                                 loss=loss, interpret=cfg.interpret)
+                                 loss=loss)
             _block(setup)
             inmem_prepare_s = time.time() - t0
             t0 = time.time()
@@ -85,7 +85,7 @@ def run(datasets=("rcv1_like", "url_small_like"), steps: int = 40,
             t0 = time.time()
             cold = DatasetStore.open(store.root)
             prep = cold.prepared()
-            _block(prep.setup_for(cold.labels(), loss, cfg.interpret))
+            _block(prep.setup_for(cold.labels(), loss))
             cold_prepare_s = time.time() - t0
             t0 = time.time()
             r_cold = solve(cold, config=cfg)
@@ -96,7 +96,7 @@ def run(datasets=("rcv1_like", "url_small_like"), steps: int = 40,
             t0 = time.time()
             warm = DatasetStore.open(store.root)
             prep = warm.prepared()
-            _block(prep.setup_for(warm.labels(), loss, cfg.interpret))
+            _block(prep.setup_for(warm.labels(), loss))
             warm_prepare_s = time.time() - t0
             t0 = time.time()
             r_warm = solve(warm, config=cfg)

@@ -12,14 +12,18 @@ Per (arch × shape) on the single-pod 16×16 mesh:
 """
 from __future__ import annotations
 
+import dataclasses
 import json
 from typing import Dict, Optional
 
 from repro.configs import ARCH_IDS, get_config
 from repro.models.config import SHAPES
-from repro.roofline.analysis import model_flops, roofline_terms, two_point_total
+from repro.roofline.analysis import (chip_peaks, model_flops, roofline_terms,
+                                     two_point_total)
 
 CHIPS = 256
+# the dry run lowers for a v5e pod
+PEAKS = chip_peaks("TPU v5 lite")
 
 # N_active for MoE archs (routed top-k + shared + attention/embed), computed
 # from the configs' analytic param counts.
@@ -103,7 +107,8 @@ def build_table(dryrun_json: str, mesh: str = "16x16") -> Dict:
                                      tp["l_full"])
         coll = sum(r["collective_bytes"].values())
         terms = roofline_terms(flops=flops, bytes_accessed=bytes_,
-                               collective_bytes=coll, chips=CHIPS)
+                               collective_bytes=coll, chips=CHIPS,
+                               **dataclasses.asdict(PEAKS))
         kind = SHAPES[shape].kind
         mf = model_flops(cfg.param_count(), _tokens(shape),
                          active_params=_active_params(arch),
@@ -111,7 +116,7 @@ def build_table(dryrun_json: str, mesh: str = "16x16") -> Dict:
         # structural (fusion-aware) memory floor; the cost_analysis bytes are
         # the zero-fusion ceiling.  Bottleneck ranking uses the floor — real
         # TPU HBM traffic sits close to it for matmul-dominated cells.
-        t_mem_floor = _struct_bytes(arch, shape) / 819e9
+        t_mem_floor = _struct_bytes(arch, shape) / PEAKS.hbm_bw
         eff = {"t_compute_s": terms["t_compute_s"],
                "t_mem_floor_s": t_mem_floor,
                "t_collective_s": terms["t_collective_s"]}

@@ -54,6 +54,8 @@ def main():
                          "engine with a batched fast path)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from repro.core.solvers import available_backends
 
     if args.backend is not None and args.backend not in available_backends():

@@ -7,11 +7,12 @@ On TPU we sample that decomposition directly:
 
     P(j) = P(group g)·P(j | g) = softmax(c)_g · softmax(v_g)_j
 
-with one Gumbel-max over the ``G = ⌈√D⌉`` group masses (a "big step") and one
-Gumbel-max over the ``M = ⌈D/G⌉`` members of the chosen group (the "little
-steps").  Both are O(√D) dense vector scans that the VPU runs at line rate;
-there is no data-dependent control flow, so the whole FW iteration stays
-inside one ``lax.scan``.
+with one Gumbel-max over the G ≈ √D group masses (a "big step") and one
+Gumbel-max over the M ≈ √D members of the chosen group (the "little
+steps"); ``group_shape`` aligns (G, M) to the TPU's (8, 128) tile.  Both
+are O(√D) dense vector scans that the VPU runs at line rate; there is no
+data-dependent control flow, so the whole FW iteration stays inside one
+``lax.scan``.
 
 State updates after a FW iteration touch ``S_c`` coordinates: we scatter the
 new log-weights and recompute the affected groups' log-sum-exps via a masked
@@ -58,15 +59,24 @@ class TwoLevelSamplerState:
         return self.v.shape[1]
 
 
-def _group_shape(d: int) -> Tuple[int, int]:
-    g = max(1, math.isqrt(max(d - 1, 0)) + 1)  # ⌈√D⌉ groups
-    m = (d + g - 1) // g
+def _round_up(x: int, k: int) -> int:
+    return -(-x // k) * k
+
+
+def group_shape(d: int) -> Tuple[int, int]:
+    """(G, M) of the member table: ~√D groups of ~√D members, aligned to
+    the TPU's (8, 128) tile — M a multiple of 128 lanes, G of 8 sublanes —
+    so the ``bsls_draw`` kernel can fetch the tile holding one group.  Item
+    j sits at flat slot j = g·M + m; the slots past D are NEG_INF padding."""
+    g0 = max(1, math.isqrt(max(d - 1, 0)) + 1)   # ⌈√D⌉
+    m = _round_up((d + g0 - 1) // g0, 128)
+    g = _round_up(max(1, (d + m - 1) // m), 8)
     return g, m
 
 
 def tl_init(log_weights: jnp.ndarray) -> TwoLevelSamplerState:
     d = log_weights.shape[0]
-    g, m = _group_shape(d)
+    g, m = group_shape(d)
     v = jnp.full((g * m,), NEG_INF, log_weights.dtype).at[:d].set(log_weights)
     v = v.reshape(g, m)
     c = jax.scipy.special.logsumexp(v, axis=1)

@@ -8,7 +8,7 @@ One API over every implementation of the paper's algorithms:
 
 Backends (``available_backends()``): ``dense`` (Alg 1), ``jax_dense`` (Alg 2,
 pure-jnp device scan), ``host_sparse`` (Alg 2, faithful host loop),
-``jax_sparse`` (Alg 2 through the Pallas kernels), ``jax_shard`` (Alg 2
+``jax_sparse`` (Alg 2 through ``repro.kernels``), ``jax_shard`` (Alg 2
 under feature sharding on ``FWConfig.mesh`` — DESIGN.md §8).  New backends
 register via ``register``.
 

@@ -32,6 +32,7 @@ so ``backend="auto"`` and vmap-vs-sequential choices see real numbers.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import List, Optional, Sequence, Tuple
 
@@ -94,16 +95,19 @@ class TuningRecord:
 
 def candidate_widths(pcsc, max_candidates: int = MAX_WIDTH_CANDIDATES
                      ) -> List[int]:
-    """Power-of-two tier widths worth probing: from the first power of two
-    at or above the 90th-percentile column nnz up to (exclusive) the flat
-    pad width.  Bounded, and empty when the layout has no tail to split."""
+    """Tier widths worth probing: whole-lane widths (``LANES`` times a power
+    of two) from the first at or above the 90th-percentile column nnz up to
+    (exclusive) the flat pad width.  Lane-aligned like the flat table, so
+    the chip keeps both tiers row-major.  Bounded, and empty when the layout
+    has no tail to split."""
+    from repro.core.sparse.formats import LANES
     full = int(pcsc.indices.shape[1])
     cn = np.asarray(pcsc.nnz)
-    if full <= 8 or cn.size == 0:
+    if full <= LANES or cn.size == 0:
         return []
-    lo = max(8, int(np.percentile(cn, 90)))
+    lo = int(np.percentile(cn, 90))
     cands = []
-    w = 8
+    w = LANES
     while w < full and len(cands) < max_candidates:
         if w >= lo:
             cands.append(w)
@@ -117,41 +121,72 @@ def _bitwise_equal(a, b) -> bool:
 
 
 def _scan_once(pcsr, csc, setup, y_scan, *, steps, loss, lam, em_scale,
-               private, interpret, seed=0):
+               private, seed=0):
     import jax
 
     from repro.core.solvers.jax_sparse import fw_scan_jit
     out = fw_scan_jit(pcsr, csc, *setup, lam, em_scale,
                       jax.random.PRNGKey(seed), 0.0, y_scan,
-                      steps=steps, loss=loss, private=private, fused=True,
-                      interpret=interpret)
+                      steps=steps, loss=loss, private=private)
     jax.block_until_ready(out[0])
     return out[:3]                       # (w, gaps, coords)
 
 
+def _chunked_once(pcsr, csc, setup, y_scan, *, steps, loss, lam, em_scale,
+                  private, chunk, seed=0):
+    """(w, gaps, coords) of the §9 chunked driver: the masked chunk program
+    re-entered every ``chunk`` steps, as ``jax_sparse._chunked_fw`` runs
+    it."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core.solvers.jax_sparse import (fw_carry_init_jit,
+                                               fw_scan_chunk_jit)
+    carry = fw_carry_init_jit(pcsr.shape[1], pcsr.values.dtype, *setup,
+                              em_scale, jax.random.PRNGKey(seed),
+                              private=private)
+    gaps, coords = [], []
+    t0 = 0
+    while t0 < steps:
+        c = min(chunk, steps - t0)
+        carry, (g, j) = fw_scan_chunk_jit(
+            pcsr, csc, carry, lam, em_scale, 0.0, t0, y_scan,
+            steps=c, loss=loss, private=private, early_stop=True)
+        gaps.append(g)
+        coords.append(j)
+        t0 += c
+    jax.block_until_ready(carry.w)
+    return carry.w * carry.w_m, jnp.concatenate(gaps), jnp.concatenate(coords)
+
+
 def probe_parity(pcsr, pcsc_default, csc_candidate, y, *, loss: str,
-                 interpret: bool, steps: int = 32, lam: float = 20.0,
-                 setup=None) -> bool:
+                 steps: int = 32, lam: float = 20.0, setup=None) -> bool:
     """The exactness gate: candidate layout must reproduce the flat layout's
-    (w, gaps, coords) **bitwise**, on a private and a non-private run."""
+    (w, gaps, coords) **bitwise**, on a private and a non-private run,
+    through both drivers — the whole-run scan and the chunked driver.  The
+    two compile the step in different contexts, so a candidate can agree
+    in one and drift by an ulp in the other."""
     import jax.numpy as jnp
 
     from repro.core.losses import get_loss
     from repro.core.solvers.jax_sparse import em_scale_for, fw_setup_jit
+    from repro.core.solvers.planner import default_chunk
     y32 = jnp.asarray(y, jnp.float32)
     if setup is None:
-        setup = fw_setup_jit(pcsr, y32, loss=loss, interpret=interpret)
+        setup = fw_setup_jit(pcsr, y32, loss=loss)
     y_scan = None if get_loss(loss).separable else y32
     for private in (False, True):
         cfg = FWConfig(steps=steps, epsilon=1.0, delta=1e-6,
                        queue="two_level" if private else "group_argmax")
         em = em_scale_for(cfg, pcsr.shape[0])
         kw = dict(steps=steps, loss=loss, lam=lam, em_scale=em,
-                  private=private, interpret=interpret)
-        ref = _scan_once(pcsr, pcsc_default, setup, y_scan, **kw)
-        got = _scan_once(pcsr, csc_candidate, setup, y_scan, **kw)
-        if not all(_bitwise_equal(r, g) for r, g in zip(ref, got)):
-            return False
+                  private=private)
+        for run in (_scan_once, functools.partial(
+                _chunked_once, chunk=default_chunk(steps))):
+            ref = run(pcsr, pcsc_default, setup, y_scan, **kw)
+            got = run(pcsr, csc_candidate, setup, y_scan, **kw)
+            if not all(_bitwise_equal(r, g) for r, g in zip(ref, got)):
+                return False
     return True
 
 
@@ -167,41 +202,25 @@ def _time_per_iter_ms(fn, steps: int, repeats: int = 3) -> float:
 
 
 def _time_layout(pcsr, csc, setup, y_scan, *, steps, loss, lam, em_scale,
-                 private, interpret) -> float:
+                 private) -> float:
     kw = dict(steps=steps, loss=loss, lam=lam, em_scale=em_scale,
-              private=private, interpret=interpret)
+              private=private)
     return _time_per_iter_ms(
         lambda: _scan_once(pcsr, csc, setup, y_scan, **kw), steps)
 
 
 def _tune_chunk(pcsr, csc, setup, y_scan, *, steps, loss, lam, em_scale,
-                private, interpret) -> Optional[int]:
+                private) -> Optional[int]:
     """Pick the chunked-driver re-entry length: time a short chunked run at
     each candidate and keep the fastest (None = planner default wins)."""
-    import jax
-
-    from repro.core.solvers.jax_sparse import fw_carry_init_jit, \
-        fw_scan_chunk_jit
     from repro.core.solvers.planner import default_chunk
-    dtype = pcsr.values.dtype
-
-    def run_chunked(chunk: int):
-        carry = fw_carry_init_jit(pcsr.shape[1], dtype, *setup, em_scale,
-                                  jax.random.PRNGKey(0), private=private)
-        t0 = 0
-        while t0 < steps:
-            c = min(chunk, steps - t0)
-            carry, _ = fw_scan_chunk_jit(
-                pcsr, csc, carry, lam, em_scale, 0.0, t0, y_scan,
-                steps=c, loss=loss, private=private, fused=True,
-                interpret=interpret, early_stop=True)
-            t0 += c
-        jax.block_until_ready(carry.w)
-
+    kw = dict(steps=steps, loss=loss, lam=lam, em_scale=em_scale,
+              private=private)
     base = default_chunk(steps)
     cands = sorted({min(c, steps) for c in (base,) + CHUNK_CANDIDATES})
-    timed = {c: _time_per_iter_ms(lambda c=c: run_chunked(c), steps)
-             for c in cands}
+    timed = {c: _time_per_iter_ms(
+        lambda c=c: _chunked_once(pcsr, csc, setup, y_scan, chunk=c, **kw),
+        steps) for c in cands}
     best = min(timed, key=timed.get)
     return None if best == base else int(best)
 
@@ -215,8 +234,7 @@ def _feed_planner(backend: str, stats, per_iter_ms: float, *, loss: str,
                         loss=loss)
 
 
-def tune_jax_sparse(pcsr, pcsc, y, *, loss: str = "logistic",
-                    interpret: bool = True, steps: int = 24,
+def tune_jax_sparse(pcsr, pcsc, y, *, loss: str = "logistic", steps: int = 24,
                     probe_steps: int = 32, lam: float = 20.0,
                     content_hash: str = "", platform: Optional[str] = None,
                     setup=None, tune_chunk: bool = True) -> TuningRecord:
@@ -236,11 +254,11 @@ def tune_jax_sparse(pcsr, pcsc, y, *, loss: str = "logistic",
     plat = platform or jax.devices()[0].platform
     y32 = jnp.asarray(y, jnp.float32)
     if setup is None:
-        setup = fw_setup_jit(pcsr, y32, loss=loss, interpret=interpret)
+        setup = fw_setup_jit(pcsr, y32, loss=loss)
     y_scan = None if get_loss(loss).separable else y32
     cfg = FWConfig(steps=steps, epsilon=1.0, delta=1e-6, queue="two_level")
     em_private = em_scale_for(cfg, pcsr.shape[0])
-    kw = dict(steps=steps, loss=loss, lam=lam, interpret=interpret)
+    kw = dict(steps=steps, loss=loss, lam=lam)
 
     def per_iter(csc) -> float:
         # both selection rules, worst case kept: the tuned layout must not
@@ -258,8 +276,7 @@ def tune_jax_sparse(pcsr, pcsc, y, *, loss: str = "logistic",
     for width in candidate_widths(pcsc):
         cand = tiered_from_padded(pcsc, width)
         if not probe_parity(pcsr, pcsc, cand, y32, loss=loss,
-                            interpret=interpret, steps=probe_steps, lam=lam,
-                            setup=setup):
+                            steps=probe_steps, lam=lam, setup=setup):
             obs.event("autotune.candidate", backend="jax_sparse", loss=loss,
                       candidate=f"tiered-{width}", parity=False)
             continue                      # exactness gate: never eligible
@@ -352,8 +369,7 @@ def tune_jax_shard(src, y, *, loss: str = "logistic", steps: int = 24,
 
 
 def autotune(data, y=None, *, backend: str = "jax_sparse",
-             loss: str = "logistic", interpret: bool = True,
-             steps: int = 24, probe_steps: int = 32, lam: float = 20.0,
+             loss: str = "logistic", steps: int = 24, probe_steps: int = 32, lam: float = 20.0,
              force: bool = False) -> TuningRecord:
     """Tune ``backend`` for one dataset; persist + replay through its store.
 
@@ -380,12 +396,12 @@ def autotune(data, y=None, *, backend: str = "jax_sparse",
         prepared = as_padded(data)
         if isinstance(prepared, PreparedDataset):
             pcsr, pcsc = prepared.pair
-            setup = prepared.setup_for(y, loss, interpret)
+            setup = prepared.setup_for(y, loss)
         else:
             pcsr, pcsc = prepared
             setup = None
         rec = tune_jax_sparse(
-            pcsr, pcsc, y, loss=loss, interpret=interpret, steps=steps,
+            pcsr, pcsc, y, loss=loss, steps=steps,
             probe_steps=probe_steps, lam=lam,
             content_hash=getattr(store, "content_hash", ""), platform=plat,
             setup=setup)

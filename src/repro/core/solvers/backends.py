@@ -8,7 +8,7 @@
   host_sparse  Alg 2 faithful sequential host implementation with exact FLOP
                accounting (repro.core.fw_sparse; queues = Alg 3 / Alg 4 /
                ablations).
-  jax_sparse   Alg 2 on device through the Pallas kernels (spmv /
+  jax_sparse   Alg 2 on device through ``repro.kernels`` (spmv /
                coord_update / bsls_draw) — the production sparse path.
   jax_shard    Alg 2 under feature sharding: the shard_map collective
                schedule of repro.distributed over an (a × b) BlockSparse
@@ -105,14 +105,14 @@ def _jax_shard_backend(data, y, config: FWConfig) -> FWResult:
 @register("jax_sparse", data_format="padded", queues=QUEUE_ALIASES["device"],
           default_queue="group_argmax", supports_screening=True,
           supports_path=True,
-          doc="Alg 2 device scan through the Pallas kernels "
+          doc="Alg 2 device scan through repro.kernels "
               "(spmv + coord_update + bsls_draw)")
 def _jax_sparse_backend(data, y, config: FWConfig) -> FWResult:
     from repro.core.solvers.jax_sparse import jax_sparse_fw
     setup = None
     if isinstance(data, PreparedDataset):
         # dataset-store path: replay the cached fw_setup state (bit-exact)
-        setup = data.setup_for(y, config.loss, config.interpret)
+        setup = data.setup_for(y, config.loss)
         pcsr, pcsc = data.pair
         # §11: the store's autotuned layout/chunk winner, when one exists —
         # parity-gated at tuning time, so iterates are bit-identical

@@ -13,7 +13,7 @@ of kernel launches.  ``solve_many`` amortizes all of it:
 Mechanics:
 
   * configs are bucketed into **sweep groups** — same backend / steps /
-    resolved queue / loss / interpret flag / mesh (everything that shapes
+    resolved queue / loss / mesh (everything that shapes
     the compiled program); λ, ε, δ and seed may vary freely inside a group;
   * ``X`` is coerced **once per data layout**, not once per config;
   * a ``jax_sparse`` group shares the config-independent ``fw_setup`` state
@@ -81,9 +81,8 @@ from repro.core.solvers.registry import (check_path_support,
 # because a λ-path is a different *control flow* — sequential-in-λ segments
 # through shared global step slots — and only identical paths can share the
 # fused-across-tenants schedule.
-GROUP_FIELDS = ("backend", "steps", "queue", "loss", "selection", "interpret",
-                "mesh", "chunk_steps", "screen_every", "screen_eps_frac",
-                "lambdas")
+GROUP_FIELDS = ("backend", "steps", "queue", "loss", "selection", "mesh",
+                "chunk_steps", "screen_every", "screen_eps_frac", "lambdas")
 
 
 def grid(base: FWConfig | None = None, **axes) -> Tuple[FWConfig, ...]:
@@ -143,7 +142,7 @@ def group_key(config: FWConfig) -> Tuple:
 
 
 def _sweep_scan(pcsr, pcsc, vbar0, qbar0, alpha0, lams, em_scales, keys,
-                y=None, *, steps, loss, private, fused, interpret):
+                y=None, *, steps, loss, private):
     """One compiled program for a whole sweep group: the vmapped T-step scan
     over shared setup state.  ``lams``/``em_scales``/``keys`` are stacked
     per-config; (v̄₀, q̄₀, α₀) come from ``fw_setup_jit`` — computed once per
@@ -155,20 +154,18 @@ def _sweep_scan(pcsr, pcsc, vbar0, qbar0, alpha0, lams, em_scales, keys,
     def one(lam, em_scale, key):
         w, gaps, coords, _ = fw_scan(
             pcsr, pcsc, vbar0, qbar0, alpha0, lam, em_scale, key, 0.0, y,
-            steps=steps, loss=loss, private=private, fused=fused,
-            interpret=interpret)
+            steps=steps, loss=loss, private=private)
         return w, gaps, coords
 
     return jax.vmap(one)(lams, em_scales, keys)
 
 
 _sweep_scan_jit = jax.jit(
-    _sweep_scan,
-    static_argnames=("steps", "loss", "private", "fused", "interpret"))
+    _sweep_scan, static_argnames=("steps", "loss", "private"))
 
 
 def _cohort_chunk(pcsr, pcsc, carry, lams, em_scales, gap_tols, t0,
-                  y=None, *, steps, loss, private, fused, interpret):
+                  y=None, *, steps, loss, private):
     """One vmapped chunk of the cohort scheduler: every lane advances
     ``steps`` masked iterations from offset ``t0`` (lanes that already hold
     their certificate stay frozen, bit-for-bit)."""
@@ -177,7 +174,6 @@ def _cohort_chunk(pcsr, pcsc, carry, lams, em_scales, gap_tols, t0,
     def one(carry_i, lam, em_scale, gap_tol):
         return fw_scan_chunk(pcsr, pcsc, carry_i, lam, em_scale, gap_tol, t0,
                              y, steps=steps, loss=loss, private=private,
-                             fused=fused, interpret=interpret,
                              early_stop=True)
 
     return jax.vmap(one, in_axes=(0, 0, 0, 0))(carry, lams, em_scales,
@@ -185,8 +181,7 @@ def _cohort_chunk(pcsr, pcsc, carry, lams, em_scales, gap_tols, t0,
 
 
 _cohort_chunk_jit = jax.jit(
-    _cohort_chunk,
-    static_argnames=("steps", "loss", "private", "fused", "interpret"))
+    _cohort_chunk, static_argnames=("steps", "loss", "private"))
 
 
 def _group_context(data, y, configs: Sequence[FWConfig]):
@@ -201,11 +196,11 @@ def _group_context(data, y, configs: Sequence[FWConfig]):
         rec = data.tuning_for("jax_sparse", c0.loss)
         if rec is not None and rec.ell_width is not None:
             pcsc = data.tuned_pcsc(rec)
-        setup = data.setup_for(y, c0.loss, c0.interpret)
+        setup = data.setup_for(y, c0.loss)
     else:
         pcsr, pcsc = data
         setup = fw_setup_jit(pcsr, jnp.asarray(y, jnp.float32),
-                             loss=c0.loss, interpret=c0.interpret)
+                             loss=c0.loss)
     n = pcsr.shape[0]
     dtype = pcsr.values.dtype
     scalars = {
@@ -240,13 +235,11 @@ def _solve_jax_sparse_group(
     c0 = configs[0]
     pcsr, pcsc, setup, sc = _group_context(data, y, configs)
     private = c0.queue == "two_level"
-    fused = True
     t0 = time.perf_counter()
     w, gaps, coords = _sweep_scan_jit(
         pcsr, pcsc, *setup, sc["lams"], sc["em_scales"], sc["keys"],
         _group_labels(c0, y),
-        steps=c0.steps, loss=c0.loss, private=private, fused=fused,
-        interpret=c0.interpret)
+        steps=c0.steps, loss=c0.loss, private=private)
     jax.block_until_ready(w)
     record_cost(c0.backend, "vmap", jax.devices()[0].platform,
                 _group_stats(pcsr, pcsc),
@@ -303,7 +296,6 @@ def _solve_jax_sparse_group_cohort(
     stats = _group_stats(pcsr, pcsc)
     platform = jax.devices()[0].platform
     private = c0.queue == "two_level"
-    fused = True
     y_scan = _group_labels(c0, y)
     n_cfg = len(configs)
     steps = c0.steps
@@ -351,8 +343,7 @@ def _solve_jax_sparse_group_cohort(
         padded, (g, j) = _cohort_chunk_jit(
             pcsr, pcsc, padded, sc["lams"][cfg_sel], sc["em_scales"][cfg_sel],
             sc["gap_tols"][cfg_sel], t0, y_scan,
-            steps=c, loss=c0.loss, private=private, fused=fused,
-            interpret=c0.interpret)
+            steps=c, loss=c0.loss, private=private)
         jax.block_until_ready(g)
         dt = time.perf_counter() - tw
         record_cost(c0.backend, "vmap", platform, stats,
@@ -422,7 +413,6 @@ def _solve_jax_sparse_path_group_fused(
     stats = _group_stats(pcsr, pcsc)
     platform = jax.devices()[0].platform
     private = c0.queue == "two_level"
-    fused = True
     y_scan = _group_labels(c0, y)
     n_cfg = len(configs)
     n, d = pcsr.shape
@@ -454,8 +444,7 @@ def _solve_jax_sparse_path_group_fused(
             tw = time.perf_counter()
             cur, (g, j) = _cohort_chunk_jit(
                 pcsr, pcsc, cur, lams, em_scales, sc["gap_tols"],
-                seg_off + t0, y_scan, steps=c, loss=c0.loss, private=private,
-                fused=fused, interpret=c0.interpret)
+                seg_off + t0, y_scan, steps=c, loss=c0.loss, private=private)
             jax.block_until_ready(g)
             record_cost(c0.backend, "vmap", platform, stats,
                         (time.perf_counter() - tw) / (c * n_cfg),

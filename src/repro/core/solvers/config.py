@@ -51,7 +51,6 @@ class FWConfig:
     epsilon: float = 1.0
     delta: float = 1e-6
     seed: int = 0
-    interpret: bool = True       # Pallas interpret mode (True on CPU containers)
     # jax_shard only: (row shards, feature shards) of the device mesh the
     # blocked solve runs on; None → 1×1 (single device — must reproduce the
     # host oracle exactly, which is what makes parity testable everywhere).

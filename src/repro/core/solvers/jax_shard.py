@@ -50,10 +50,8 @@ def make_shard_mesh(a: int, b: int):
         raise ValueError(
             f"FWConfig.mesh=({a}, {b}) needs {a * b} devices but only "
             f"{jax.device_count()} are visible")
-    if hasattr(jax.sharding, "AxisType"):  # jax ≥ 0.5 explicit-axis-type API
-        return jax.make_mesh((a, b), ("data", "model"),
-                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    return jax.make_mesh((a, b), ("data", "model"))
+    return jax.make_mesh((a, b), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def mesh_grid(config: FWConfig, src: ShardSource = None) -> Tuple[int, int]:

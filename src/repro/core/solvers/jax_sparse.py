@@ -1,25 +1,23 @@
-"""``jax_sparse`` backend — Algorithm 2 as one device-resident kernel pipeline.
+"""``jax_sparse`` backend — Algorithm 2 as one device-resident program.
 
-This is the paper's fast iteration finally wired end-to-end through the
-Pallas kernels (DESIGN.md §5):
+This is the paper's fast iteration wired end-to-end through ``kernels/``
+(DESIGN.md §5):
 
   * setup           — ``kernels/spmv`` ELL rmatvec builds ȳ and α₀ from the
                       padded CSR (one O(nnz) sweep each);
   * line 15 select  — ``kernels/bsls_draw`` two-level exponential-mechanism
                       draw (big step over √D group masses in XLA, little step
                       as the scalar-prefetch Pallas kernel that DMAs only the
-                      winning group's row), or the lazy group-argmax for the
+                      winning group's tile), or the lazy group-argmax for the
                       non-private queue;
-  * lines 22-28     — ``kernels/coord_update`` fused sweep: one VMEM-resident
-                      pass updates v̄, q̄, α and returns the g̃ increment,
-                      instead of the four separate scatter/gather passes the
-                      pure-jnp ``fw_jax`` path emits.
+  * lines 22-28     — ``kernels/coord_update``: one coordinate's v̄, q̄, α and
+                      g̃ increment from the selected column's tile.
 
 The T-iteration loop is a single ``lax.scan``, so the whole optimization
 lowers to one XLA while-loop with the kernels inlined — jit/pjit-compilable
-and droppable onto the production mesh.  On CPU containers the kernels run in
-interpret mode (``config.interpret=True``, the default); on TPU pass
-``interpret=False``.
+and droppable onto the production mesh.  The Pallas kernel is compiled when
+the program is lowered for a TPU and interpreted on any other platform
+(``kernels.platform_kernel``); nothing in the config chooses it.
 
 State representation (w_m-rescaling) is identical to ``fw_sparse``/``fw_jax``
 — see DESIGN.md §2 — so the non-private path takes the *same steps* as both,
@@ -65,14 +63,23 @@ from repro.core.solvers.stopping import (assemble_outputs, drive_chunks,
 from repro.core.sparse.formats import PaddedCSC, PaddedCSR, TieredCSC
 from repro.kernels.bsls_draw.ops import two_level_draw
 from repro.kernels.coord_update.ops import coord_update
-from repro.kernels.coord_update.ref import coord_update_ref
 from repro.kernels.spmv.ops import ell_rmatvec
 
 
+# Rows of column j's tile per coordinate-update chunk: one lane-width.
+TILE_ROWS = 128
+
+
+def _pad_rows(a: jnp.ndarray) -> jnp.ndarray:
+    """A (K,) column-tile vector padded with inert zeros / False to a whole
+    number of ``TILE_ROWS`` chunks."""
+    return jnp.pad(a, (0, -a.shape[0] % TILE_ROWS))
+
+
 def fw_setup(
-    pcsr: PaddedCSR, y: jnp.ndarray, *, loss: str, interpret: bool
+    pcsr: PaddedCSR, y: jnp.ndarray, *, loss: str
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Config-independent solve state: (v̄₀, q̄₀, α₀) via the spmv kernel.
+    """Config-independent solve state: (v̄₀, q̄₀, α₀) via the spmv sweep.
 
     Depends only on (X, y, loss) — a λ/ε sweep over one design matrix
     computes this once and shares it across every problem in the batch.
@@ -86,12 +93,12 @@ def fw_setup(
     vbar0 = jnp.zeros(n, dtype)
     if obj.separable:
         h = obj.split_grad
-        ybar = ell_rmatvec(pcsr, y, interpret=interpret) / n
+        ybar = ell_rmatvec(pcsr, y) / n
         qbar0 = h(vbar0)
-        alpha0 = ell_rmatvec(pcsr, qbar0, interpret=interpret) / n - ybar
+        alpha0 = ell_rmatvec(pcsr, qbar0) / n - ybar
     else:
         qbar0 = obj.grad(vbar0, y)
-        alpha0 = ell_rmatvec(pcsr, qbar0, interpret=interpret) / n
+        alpha0 = ell_rmatvec(pcsr, qbar0) / n
     return vbar0, qbar0, alpha0
 
 
@@ -135,8 +142,7 @@ def fw_carry_init(
 def fw_scan_chunk(
     pcsr: PaddedCSR, pcsc, carry: FWCarry,
     lam, em_scale, gap_tol, t0, y=None,
-    *, steps: int, loss: str, private: bool, fused: bool, interpret: bool,
-    early_stop: bool = False,
+    *, steps: int, loss: str, private: bool, early_stop: bool = False,
 ) -> Tuple[FWCarry, Tuple[jnp.ndarray, jnp.ndarray]]:
     """Advance the carry by ``steps`` iterations starting after global step
     ``t0``; returns (carry, (gaps, coords)) for this chunk.
@@ -145,9 +151,9 @@ def fw_scan_chunk(
     scale; 1.0 when non-private), ``gap_tol`` and ``t0`` may be traced
     scalars — the first two are the vmap axis of ``solvers.batched``, the
     offset is what lets one compiled chunk be re-entered across a run.
-    Everything shape- or branch-affecting (``steps``/``private``/``fused``/
-    ``interpret``/``early_stop``) is static, which is exactly what makes a
-    sweep group batchable.
+    Everything shape- or branch-affecting (``steps``/``private``/
+    ``early_stop``) is static, which is exactly what makes a sweep group
+    batchable.
 
     With ``early_stop`` the scan is masked: the iteration that observes
     g_t ≤ gap_tol is still applied (the certificate speaks for the iterate it
@@ -165,7 +171,6 @@ def fw_scan_chunk(
     obj = get_loss(loss)
     if not obj.separable and y is None:
         raise ValueError(f"loss {loss!r} is label-coupled; pass y")
-    h = obj.split_grad
     dtype = pcsr.values.dtype
     inv_n = 1.0 / n
     lam = jnp.asarray(lam, dtype)
@@ -180,7 +185,7 @@ def fw_scan_chunk(
         key_next, sel_key = jax.random.split(key)
         # ---- line 15: select coordinate -------------------------------------
         if private:
-            j = two_level_draw(sampler.c, sampler.v, sel_key, interpret=interpret)
+            j = two_level_draw(sampler.c, sampler.v, sel_key)
             sampler_after_sel = sampler
         else:
             j, sampler_after_sel = ga_get_next(sampler)
@@ -194,47 +199,51 @@ def fw_scan_chunk(
         w_m = w_m * (1.0 - eta)
         w = w.at[j].add(eta * d_tilde / w_m)
         g_tilde = g_tilde * (1.0 - eta) + eta * d_tilde * a_j
-        # ---- lines 22-28: one fused VMEM sweep ------------------------------
+        # ---- lines 22-28: the coordinate update over column j's tile -------
         def apply_tile(col):
-            """Lines 22-29 on one column tile: the fused coordinate update
-            plus the queue refresh of every touched coordinate.  The tile
-            width is whatever the layout hands us — the flat (Kc,) lanes, or
-            one tier of the autotuned split; padded lanes are inert either
-            way, so the tier only changes how many zero lanes ride along."""
-            rows, xvals, mask = col                      # (K,)
-            row_idx = pcsr.indices[rows]                 # (K, Kr)
-            row_val = pcsr.values[rows]                  # (K, Kr) — 0 at padding
-            y_col = None if obj.separable else y[rows]
-            if fused:
-                vbar_t, qbar_t, alpha_t, g_delta = coord_update(
-                    vbar, qbar, alpha, w, rows, xvals, mask, row_idx, row_val,
+            """Lines 22-29 on column j's tile, ``TILE_ROWS`` rows at a
+            time: the coordinate update, then the queue refresh of every
+            coordinate those rows touch.  Only the chunks holding j's nnz
+            rows run, so a step moves O(nnz_j·Kr) lanes rather than the
+            padded width's — the densest column sets the layout's width,
+            not every step's cost.  Rows are distinct, so the chunks' v̄/q̄
+            updates commute; a coordinate touched by several chunks is
+            refreshed after each, the last time with its final α."""
+            rows, xvals, mask = (_pad_rows(a) for a in col)   # (K,)
+            n_chunks = (jnp.sum(mask) + TILE_ROWS - 1) // TILE_ROWS
+
+            def chunk(c, state):
+                vbar, qbar, alpha, g_tilde, sampler = state
+                r, x, m = (jax.lax.dynamic_slice_in_dim(
+                    a, c * TILE_ROWS, TILE_ROWS) for a in (rows, xvals, mask))
+                row_idx = pcsr.indices[r]                # (C, Kr)
+                row_val = pcsr.values[r]                 # (C, Kr) — 0 at padding
+                y_col = None if obj.separable else y[r]
+                vbar, qbar, alpha, g_c = coord_update(
+                    vbar, qbar, alpha, w, r, x, m, row_idx, row_val,
                     eta=eta, d_tilde=d_tilde, w_m=w_m, inv_n=inv_n,
-                    loss=loss, y_col=y_col, interpret=interpret)
-            else:
-                vbar_t, qbar_t, alpha_t, g_delta = coord_update_ref(
-                    vbar, qbar, alpha, w, rows, xvals, mask, row_idx, row_val,
-                    eta=eta, d_tilde=d_tilde, w_m=w_m, inv_n=inv_n,
-                    h=h if obj.separable else obj.grad, y_col=y_col)
-            # line 29: refresh queue priorities for touched coordinates
-            flat_idx = row_idx.reshape(-1)
-            fresh = jnp.abs(alpha_t[flat_idx]) * (em_scale if private
-                                                  else 1.0)
-            if private:
-                sampler_t = tl_update(sampler_after_sel, flat_idx, fresh)
-            else:
-                sampler_t = ga_update(sampler_after_sel, flat_idx, fresh)
-            return vbar_t, qbar_t, alpha_t, g_delta, sampler_t
+                    loss=loss, y_col=y_col)
+                # line 29: refresh queue priorities for touched coordinates
+                flat_idx = row_idx.reshape(-1)
+                fresh = jnp.abs(alpha[flat_idx]) * (em_scale if private
+                                                    else 1.0)
+                refresh = tl_update if private else ga_update
+                return (vbar, qbar, alpha, g_tilde + g_c,
+                        refresh(sampler, flat_idx, fresh))
+
+            return jax.lax.fori_loop(
+                0, n_chunks, chunk,
+                (vbar, qbar, alpha, g_tilde, sampler_after_sel))
 
         if isinstance(pcsc, TieredCSC):
             # §11 tiered layout: the few heavy columns run the full-width
             # tile, everything else the narrow one — same sums, fewer lanes
-            vbar, qbar, alpha, g_delta, sampler = jax.lax.cond(
+            vbar, qbar, alpha, g_tilde, sampler = jax.lax.cond(
                 pcsc.is_heavy(j),
                 lambda: apply_tile(pcsc.col_heavy(j)),
                 lambda: apply_tile(pcsc.col_light(j)))
         else:
-            vbar, qbar, alpha, g_delta, sampler = apply_tile(pcsc.col(j))
-        g_tilde = g_tilde + g_delta
+            vbar, qbar, alpha, g_tilde, sampler = apply_tile(pcsc.col(j))
         new = FWCarry(w, w_m, g_tilde, vbar, qbar, alpha, sampler, key_next,
                       done, stop_at)
         if not early_stop:
@@ -261,8 +270,7 @@ def fw_scan(
     pcsr: PaddedCSR, pcsc,
     vbar0: jnp.ndarray, qbar0: jnp.ndarray, alpha0: jnp.ndarray,
     lam, em_scale, key: jax.Array, gap_tol=0.0, y=None,
-    *, steps: int, loss: str, private: bool, fused: bool, interpret: bool,
-    early_stop: bool = False,
+    *, steps: int, loss: str, private: bool, early_stop: bool = False,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Whole run as one scan; returns (w, gaps, coords, stop_step).
 
@@ -274,22 +282,17 @@ def fw_scan(
                            em_scale, key, private=private)
     carry, (gaps, coords) = fw_scan_chunk(
         pcsr, pcsc, carry0, lam, em_scale, gap_tol, 0, y,
-        steps=steps, loss=loss, private=private, fused=fused,
-        interpret=interpret, early_stop=early_stop)
+        steps=steps, loss=loss, private=private, early_stop=early_stop)
     stop_step = jnp.where(carry.done, carry.stop_at,
                           jnp.asarray(steps, jnp.int32))
     return carry.w * carry.w_m, gaps, coords, stop_step
 
 
-fw_setup_jit = jax.jit(fw_setup, static_argnames=("loss", "interpret"))
+fw_setup_jit = jax.jit(fw_setup, static_argnames=("loss",))
 fw_scan_jit = jax.jit(
-    fw_scan,
-    static_argnames=("steps", "loss", "private", "fused", "interpret",
-                     "early_stop"))
+    fw_scan, static_argnames=("steps", "loss", "private", "early_stop"))
 fw_scan_chunk_jit = jax.jit(
-    fw_scan_chunk,
-    static_argnames=("steps", "loss", "private", "fused", "interpret",
-                     "early_stop"))
+    fw_scan_chunk, static_argnames=("steps", "loss", "private", "early_stop"))
 fw_carry_init_jit = jax.jit(fw_carry_init, static_argnames=("d", "dtype",
                                                             "private"))
 
@@ -314,7 +317,7 @@ def em_scale_for(config: FWConfig, n_rows: int) -> float:
 
 
 def _chunked_fw(pcsr, pcsc, setup, config: FWConfig, em_scale: float,
-                private: bool, fused: bool, y=None) -> FWResult:
+                private: bool, y=None) -> FWResult:
     """Host-driven chunk loop: re-enter one compiled ``fw_scan_chunk`` until
     the gap certificate lands, ``max_seconds`` expires, or T is spent
     (shared driver/assembly contract: ``solvers.stopping``)."""
@@ -326,8 +329,7 @@ def _chunked_fw(pcsr, pcsc, setup, config: FWConfig, em_scale: float,
     def advance(carry, t0, c):
         return fw_scan_chunk_jit(
             pcsr, pcsc, carry, config.lam, em_scale, config.gap_tol, t0, y,
-            steps=c, loss=config.loss, private=private, fused=fused,
-            interpret=config.interpret, early_stop=True)
+            steps=c, loss=config.loss, private=private, early_stop=True)
 
     carry, outs, stop_step, stop_reason = drive_chunks(
         advance, carry0, steps=config.steps, chunk=resolve_chunk(config),
@@ -340,7 +342,7 @@ def _chunked_fw(pcsr, pcsc, setup, config: FWConfig, em_scale: float,
 
 
 def _screened_chunked_fw(pcsr, pcsc, setup, config: FWConfig,
-                         em_scale: float, private: bool, fused: bool,
+                         em_scale: float, private: bool,
                          y=None) -> FWResult:
     """§13 screened chunk loop: the §9 driver with mutable problem geometry.
 
@@ -390,8 +392,7 @@ def _screened_chunked_fw(pcsr, pcsc, setup, config: FWConfig,
         tw = _time.perf_counter()
         carry, out = fw_scan_chunk_jit(
             p, q, carry, config.lam, em_scale, config.gap_tol, t0, y,
-            steps=c, loss=config.loss, private=private, fused=fused,
-            interpret=config.interpret, early_stop=True)
+            steps=c, loss=config.loss, private=private, early_stop=True)
         jax.block_until_ready(out[0])
         record_cost("jax_sparse", "sequential", platform, cur_stats(),
                     (_time.perf_counter() - tw) / c, loss=config.loss)
@@ -445,30 +446,26 @@ def jax_sparse_fw(
     """
     n, _ = pcsr.shape
     private = config.queue == "two_level"
-    # Every registered objective lowers its own fused kernel.
-    fused = True
     em_scale = em_scale_for(config, n)
     y_scan = None if config.loss_fn().separable else jnp.asarray(y)
 
     from repro import obs
     if setup is None:
         with obs.span("solve.setup", loss=config.loss):
-            setup = fw_setup_jit(pcsr, y, loss=config.loss,
-                                 interpret=config.interpret)
+            setup = fw_setup_jit(pcsr, y, loss=config.loss)
     if config.screen_every > 0:
         # §13: mutable-geometry chunked driver (subsumes early stopping)
         return _screened_chunked_fw(pcsr, pcsc, setup, config, em_scale,
-                                    private, fused, y=y_scan)
+                                    private, y=y_scan)
     if config.early_stopping:
         return _chunked_fw(pcsr, pcsc, setup, config, em_scale, private,
-                           fused, y=y_scan)
+                           y=y_scan)
     vbar0, qbar0, alpha0 = setup
     with obs.span("solve.scan", steps=config.steps, private=private):
         w, gaps, coords, stop_step = fw_scan_jit(
             pcsr, pcsc, vbar0, qbar0, alpha0,
             config.lam, em_scale, jax.random.PRNGKey(config.seed), 0.0,
-            y_scan, steps=config.steps, loss=config.loss, private=private,
-            fused=fused, interpret=config.interpret)
+            y_scan, steps=config.steps, loss=config.loss, private=private)
     return FWResult(w=w, gaps=gaps, coords=coords,
                     losses=jnp.zeros_like(gaps), stop_step=config.steps,
                     stop_reason=STOP_MAX_STEPS)

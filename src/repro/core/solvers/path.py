@@ -210,7 +210,7 @@ def path_em_scale(config: FWConfig, plan: PathPlan, n_rows: int) -> float:
 
 def jax_sparse_path(pcsr, pcsc, y, config: FWConfig, plan: PathPlan = None,
                     setup=None) -> PathResult:
-    """Warm-started λ-path through the Pallas kernel pipeline.
+    """Warm-started λ-path through the ``jax_sparse`` kernel pipeline.
 
     One :class:`jax_sparse.FWCarry` is threaded across every λ-segment;
     between segments only the §9 stopping flags (``done``/``stop_at``) are
@@ -228,15 +228,13 @@ def jax_sparse_path(pcsr, pcsc, y, config: FWConfig, plan: PathPlan = None,
     private = config.queue == "two_level"
     if plan is None:
         plan = path_plan(config, private=private)
-    fused = True
     n, d = pcsr.shape
     dtype = pcsr.values.dtype
     em_scale = path_em_scale(config, plan, n)
     y_scan = None if config.loss_fn().separable else jnp.asarray(y)
     if setup is None:
         with obs.span("solve.setup", loss=config.loss):
-            setup = fw_setup_jit(pcsr, y, loss=config.loss,
-                                 interpret=config.interpret)
+            setup = fw_setup_jit(pcsr, y, loss=config.loss)
     carry = fw_carry_init_jit(d, dtype, *setup, em_scale,
                               jax.random.PRNGKey(config.seed),
                               private=private)
@@ -255,8 +253,7 @@ def jax_sparse_path(pcsr, pcsc, y, config: FWConfig, plan: PathPlan = None,
             return fw_scan_chunk_jit(
                 pcsr, pcsc, carry, _lam, em_scale, config.gap_tol,
                 _off + t0, y_scan, steps=c, loss=config.loss,
-                private=private, fused=fused, interpret=config.interpret,
-                early_stop=True)
+                private=private, early_stop=True)
 
         t_seg = time.perf_counter()
         chunk = resolve_chunk(dataclasses.replace(config, steps=budget))
@@ -332,7 +329,7 @@ def run_path(backend, data, y, config: FWConfig) -> PathResult:
         setup = None
         if isinstance(data, PreparedDataset):
             # dataset-store path: cached fw_setup replay + §11 tuned layout
-            setup = data.setup_for(y, config.loss, config.interpret)
+            setup = data.setup_for(y, config.loss)
             pcsr, pcsc = data.pair
             rec = data.tuning_for("jax_sparse", config.loss)
             if rec is not None:

@@ -40,12 +40,13 @@ import numpy as np
 
 from repro import obs
 from repro.core.solvers.config import FWConfig
-from repro.roofline.analysis import roofline_terms
+from repro.roofline.analysis import chip_peaks, roofline_terms
 
-# Effective per-chip rates fed to roofline_terms.  The TPU numbers live in
-# repro.roofline.analysis; the CPU numbers are deliberately conservative
-# (one wide core of a shared CI container) — only *ratios* between candidate
-# plans matter here, not absolute seconds.
+# Effective per-chip rates fed to roofline_terms.  An accelerator's come from
+# repro.roofline.analysis.CHIP_PEAKS by device kind (an unknown chip raises);
+# the CPU numbers are deliberately conservative (one wide core of a shared CI
+# container) — only *ratios* between candidate plans matter here, not
+# absolute seconds.
 CPU_PEAK_FLOPS = 2.0e10
 CPU_HBM_BW = 1.5e10
 # Measured lane overhead of vmapping the kernel scan on CPU interpret mode:
@@ -179,15 +180,19 @@ def step_costs(stats: ProblemStats, backend: str) -> Tuple[float, float]:
 
 def step_time_model(stats: ProblemStats, backend: str,
                     platform: str) -> float:
-    """Modeled seconds per FW iteration on ``platform`` (roofline bound)."""
+    """Modeled seconds per FW iteration on ``platform`` (roofline bound).
+
+    Off the CPU the peaks are those of the local device's kind
+    (``CHIP_PEAKS``); a chip without published peaks raises."""
     flops, bytes_ = step_costs(stats, backend)
     if platform == "cpu":
-        terms = roofline_terms(flops=flops, bytes_accessed=bytes_,
-                               collective_bytes=0.0, chips=1,
-                               peak_flops=CPU_PEAK_FLOPS, hbm_bw=CPU_HBM_BW)
+        peaks = dict(peak_flops=CPU_PEAK_FLOPS, hbm_bw=CPU_HBM_BW,
+                     ici_bw=float("inf"))      # one host: no link term
     else:
-        terms = roofline_terms(flops=flops, bytes_accessed=bytes_,
-                               collective_bytes=0.0, chips=1)
+        import jax
+        peaks = dataclasses.asdict(chip_peaks(jax.devices()[0].device_kind))
+    terms = roofline_terms(flops=flops, bytes_accessed=bytes_,
+                           collective_bytes=0.0, chips=1, **peaks)
     return float(terms["t_bound_s"])
 
 

@@ -3,7 +3,7 @@
 ``PreparedDataset`` is what the solver registry's padded coercion returns
 for a ``repro.data.store.DatasetStore``: the ``(PaddedCSR, PaddedCSC)`` pair
 plus a memo of the config-independent Frank-Wolfe setup state
-``(v̄₀, q̄₀, α₀)`` per (loss, interpret) — the O(NS) spmv sweep
+``(v̄₀, q̄₀, α₀)`` per loss — the O(NS) spmv sweep
 ``jax_sparse.fw_setup`` would otherwise re-run on every solve.
 
 Exactness contract: on a cache miss the setup is computed by the *same*
@@ -28,8 +28,8 @@ import numpy as np
 from repro.core.sparse.formats import PaddedCSC, PaddedCSR
 
 SetupState = Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]  # (v̄₀, q̄₀, α₀)
-SetupLoader = Callable[[str, bool], Optional[SetupState]]
-SetupSaver = Callable[[str, bool, SetupState], None]
+SetupLoader = Callable[[str], Optional[SetupState]]
+SetupSaver = Callable[[str, SetupState], None]
 # (backend, loss, platform) -> persisted autotune.TuningRecord or None
 TuningLoader = Callable[[str, str, str], Optional[object]]
 
@@ -44,7 +44,7 @@ class PreparedDataset:
     loader: Optional[SetupLoader] = None  # disk-cache read hook (store)
     saver: Optional[SetupSaver] = None    # disk-cache write hook (store)
     tuning_loader: Optional[TuningLoader] = None   # §11 autotune replay hook
-    _setup: Dict[Tuple[str, bool], SetupState] = dataclasses.field(
+    _setup: Dict[str, SetupState] = dataclasses.field(
         default_factory=dict)
     # (backend, loss, platform) -> TuningRecord | None (None memoizes a miss)
     _tuning: Dict[Tuple[str, str, str], Optional[object]] = dataclasses.field(
@@ -63,24 +63,23 @@ class PreparedDataset:
         y = np.asarray(y, dtype=np.float64)
         return y.shape == self.y.shape and bool(np.array_equal(y, self.y))
 
-    def setup_for(self, y, loss: str, interpret: bool) -> SetupState:
+    def setup_for(self, y, loss: str) -> SetupState:
         """(v̄₀, q̄₀, α₀) for this dataset — cached, disk-backed, exact."""
         from repro.core.solvers.jax_sparse import fw_setup_jit
         if not self._bound_labels(y):
             # foreign labels: correct answer, but never cached
             return fw_setup_jit(self.pcsr, jnp.asarray(y, jnp.float32),
-                                loss=loss, interpret=interpret)
-        key = (loss, bool(interpret))
-        if key not in self._setup:
-            state = self.loader(loss, interpret) if self.loader else None
+                                loss=loss)
+        if loss not in self._setup:
+            state = self.loader(loss) if self.loader else None
             if state is None:
                 state = fw_setup_jit(self.pcsr,
                                      jnp.asarray(self.y, jnp.float32),
-                                     loss=loss, interpret=interpret)
+                                     loss=loss)
                 if self.saver is not None:
-                    self.saver(loss, interpret, state)
-            self._setup[key] = tuple(jnp.asarray(s) for s in state)
-        return self._setup[key]
+                    self.saver(loss, state)
+            self._setup[loss] = tuple(jnp.asarray(s) for s in state)
+        return self._setup[loss]
 
     # ------------------------------------------------- §11 autotuned layout
     def tuning_for(self, backend: str, loss: str,

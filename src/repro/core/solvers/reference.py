@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.losses import get_loss
-from repro.core.samplers.bsls_jax import NEG_INF, _group_shape
+from repro.core.samplers.bsls_jax import NEG_INF, group_shape
 from repro.core.sparse.formats import PaddedCSC, PaddedCSR
 
 
@@ -65,7 +65,7 @@ def reference_fw(pcsr: PaddedCSR, pcsc: PaddedCSC, y, *, lam: float,
         qbar = obj.grad(vbar, y)
         alpha = _ell_rmatvec_ref(pcsr, qbar) * inv_n
 
-    g_grp, m_grp = _group_shape(d)
+    g_grp, m_grp = group_shape(d)
     w = jnp.zeros(d, dtype)
     w_m = jnp.asarray(1.0, dtype)
     g_tilde = jnp.asarray(0.0, dtype)

@@ -60,7 +60,7 @@ from repro import obs
 from repro.core.dp.accountant import per_step_epsilon
 from repro.core.solvers.config import FWConfig
 from repro.core.sparse.formats import (PaddedCSC, PaddedCSR, TieredCSC,
-                                       tiered_from_padded)
+                                       lane_padded, tiered_from_padded)
 
 # Survivor floor: never screen below max(DEFAULT_MIN_KEEP, √D₀) coordinates —
 # the later FW iterations need a working set, and √D is the natural group
@@ -224,7 +224,7 @@ def repack_pair(
     new_csr = repack_csr(pcsr, keep)
     ci, cv, cn = _csc_full_arrays(pcsc)
     ci2, cv2, cn2 = ci[sel], cv[sel], cn[sel].astype(np.int32)
-    k_col = max(1, int(cn2.max()) if cn2.size else 1)
+    k_col = lane_padded(cn2.max() if cn2.size else 1)
     flat = PaddedCSC(jnp.asarray(ci2[:, :k_col].astype(np.int32)),
                      jnp.asarray(cv2[:, :k_col].astype(np.float32)),
                      jnp.asarray(cn2), (pcsr.shape[0], int(sel.size)))

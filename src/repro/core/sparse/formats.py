@@ -26,6 +26,17 @@ import numpy as np
 
 Shape = Tuple[int, int]
 
+# TPU vector lanes.  A (D, K) column table whose K is a multiple of this is
+# laid out row-major on the chip, so a FW step reads column j as one
+# contiguous row; any other K gets a column-major layout and the scan copies
+# the whole table into row-major order first (a second resident copy).
+LANES = 128
+
+
+def lane_padded(k: int) -> int:
+    """Pad width ``k`` rounded up to whole 128-lane vectors."""
+    return -(-max(int(k), 1) // LANES) * LANES
+
 
 # ---------------------------------------------------------------------------
 # Host (numpy, exact) formats
@@ -209,7 +220,10 @@ class PaddedCSR:
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class PaddedCSC:
-    """Padded CSC: per-column row ids.  Column j's rows = ``indices[j]``."""
+    """Padded CSC: per-column row ids.  Column j's rows = ``indices[j]``.
+
+    The pad width K is the max column nnz rounded up to whole lanes
+    (``lane_padded``), so the chip keeps the table row-major."""
 
     indices: jnp.ndarray  # (D, K) int32 row ids
     values: jnp.ndarray   # (D, K) float
@@ -346,7 +360,7 @@ def host_to_padded(csr: HostCSR) -> Tuple[PaddedCSR, PaddedCSC]:
     n, d = csr.shape
     csc = csr.tocsc()
     k_row = int(max(1, np.max(np.diff(csr.indptr)) if csr.nnz else 1))
-    k_col = int(max(1, np.max(np.diff(csc.indptr)) if csc.nnz else 1))
+    k_col = lane_padded(np.max(np.diff(csc.indptr)) if csc.nnz else 1)
     ri, rv, rn = _pad_rows(csr.indptr, csr.indices, csr.data, n, k_row)
     ci, cv, cn = _pad_rows(csc.indptr, csc.indices, csc.data, d, k_col)
     pcsr = PaddedCSR(jnp.asarray(ri), jnp.asarray(rv), jnp.asarray(rn), (n, d))

@@ -12,7 +12,7 @@ re-reads.  ``DatasetStore`` materializes a dataset **once**:
       shard-00000.y.npy               float64 (rows,)
       colstats.npz                    df / norm_sq / col_sum / col_y_sum
       cache/padded-{csr,csc}.*.npy    ELL padded device layout (mmap-read)
-      cache/setup-<loss>-<mode>.npz   fw_setup state (v̄₀, q̄₀, α₀), float32
+      cache/setup-<loss>-<platform>.npz fw_setup state (v̄₀, q̄₀, α₀), float32
 
 * **Ingestion is streaming**: ``DatasetStore.write`` consumes the chunk
   protocol of ``repro.data.sparse_io`` (libsvm parser or in-memory adapter),
@@ -469,13 +469,13 @@ class DatasetStore:
             return None
         with open(meta_path) as f:
             meta = json.load(f)
-        if meta.get("content_hash") != self.content_hash:
+        from repro.core.sparse.formats import LANES, PaddedCSC, PaddedCSR
+        if (meta.get("content_hash") != self.content_hash
+                or meta.get("lanes") != LANES):
             _cache_count("padded", hit=False)
             return None
         _cache_count("padded", hit=True)
         import jax.numpy as jnp
-
-        from repro.core.sparse.formats import PaddedCSC, PaddedCSR
 
         def arrays(kind):
             base = os.path.join(self.root, CACHE_DIR, f"padded-{kind}")
@@ -493,8 +493,9 @@ class DatasetStore:
             np.save(f"{base}.indices.npy", np.asarray(p.indices))
             np.save(f"{base}.values.npy", np.asarray(p.values))
             np.save(f"{base}.nnz.npy", np.asarray(p.nnz))
+        from repro.core.sparse.formats import LANES
         with open(self._padded_meta_path(), "w") as f:
-            json.dump({"content_hash": self.content_hash}, f)
+            json.dump({"content_hash": self.content_hash, "lanes": LANES}, f)
 
     def _blocks_meta_path(self, a: int, b: int) -> str:
         return os.path.join(self.root, CACHE_DIR, f"blocks-{a}x{b}-meta.json")
@@ -513,17 +514,18 @@ class DatasetStore:
             return None
         with open(meta_path) as f:
             meta = json.load(f)
-        if meta.get("content_hash") != self.content_hash:
+        from repro.core.sparse.formats import LANES
+        if (meta.get("content_hash") != self.content_hash
+                or meta.get("lanes") != LANES):
             _cache_count("blocks", hit=False)
             return None
         _cache_count("blocks", hit=True)
-        import jax.numpy as jnp
-
         from repro.distributed.block_sparse import BlockSparse
         base = os.path.join(self.root, CACHE_DIR, f"blocks-{a}x{b}")
-        arrays = {
-            part: jnp.asarray(np.load(f"{base}.{part}.npy", mmap_mode="r"))
-            for part in ("csc_rows", "csc_vals", "csr_cols", "csr_vals")}
+        # host (mmap) arrays: the jax_shard backend places them on its mesh
+        arrays = {part: np.load(f"{base}.{part}.npy", mmap_mode="r")
+                  for part in ("csc_rows", "csc_vals", "csr_cols",
+                               "csr_vals")}
         return BlockSparse(shape=tuple(meta["shape"]),
                            padded=tuple(meta["padded"]), **arrays)
 
@@ -532,8 +534,9 @@ class DatasetStore:
         base = os.path.join(self.root, CACHE_DIR, f"blocks-{a}x{b}")
         for part in ("csc_rows", "csc_vals", "csr_cols", "csr_vals"):
             np.save(f"{base}.{part}.npy", np.asarray(getattr(blocks, part)))
+        from repro.core.sparse.formats import LANES
         with open(self._blocks_meta_path(a, b), "w") as f:
-            json.dump({"content_hash": self.content_hash,
+            json.dump({"content_hash": self.content_hash, "lanes": LANES,
                        "shape": list(blocks.shape),
                        "padded": list(blocks.padded)}, f)
 
@@ -566,12 +569,15 @@ class DatasetStore:
         with open(path, "w") as f:
             json.dump(record.to_json(), f, indent=1)
 
-    def _setup_cache_path(self, loss: str, interpret: bool) -> str:
-        mode = "interp" if interpret else "compiled"
-        return os.path.join(self.root, CACHE_DIR, f"setup-{loss}-{mode}.npz")
+    def _setup_cache_path(self, loss: str) -> str:
+        # per platform: each backend rounds the setup sweep its own way, and
+        # a replay must be bit-identical to what this platform computes
+        import jax
+        return os.path.join(self.root, CACHE_DIR,
+                            f"setup-{loss}-{jax.default_backend()}.npz")
 
-    def _setup_load(self, loss: str, interpret: bool):
-        path = self._setup_cache_path(loss, interpret)
+    def _setup_load(self, loss: str):
+        path = self._setup_cache_path(loss)
         if not os.path.exists(path):
             _cache_count("setup", hit=False)
             return None
@@ -584,10 +590,10 @@ class DatasetStore:
             return (jnp.asarray(z["vbar0"]), jnp.asarray(z["qbar0"]),
                     jnp.asarray(z["alpha0"]))
 
-    def _setup_save(self, loss: str, interpret: bool, state) -> None:
+    def _setup_save(self, loss: str, state) -> None:
         vbar0, qbar0, alpha0 = (np.asarray(s) for s in state)
         os.makedirs(os.path.join(self.root, CACHE_DIR), exist_ok=True)
-        np.savez(self._setup_cache_path(loss, interpret),
+        np.savez(self._setup_cache_path(loss),
                  vbar0=vbar0, qbar0=qbar0, alpha0=alpha0,
                  content_hash=np.array(self.content_hash))
 

@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.sparse.formats import HostCSR
+from repro.core.sparse.formats import HostCSR, lane_padded
 
 
 @jax.tree_util.register_pytree_node_class
@@ -115,7 +115,7 @@ class BlockAssembler:
     def alloc(self) -> None:
         """Fix (Kc, Kr) from the counts and allocate the padded arrays."""
         a, b = self.a, self.b
-        self.kc = max(1, int(self._col_counts.max(initial=0)))
+        self.kc = lane_padded(self._col_counts.max(initial=0))
         self.kr = max(1, int(self._row_counts.max(initial=0)))
         self._arrays = (
             np.zeros((a, b, self.d_loc, self.kc), np.int32),
@@ -147,10 +147,12 @@ class BlockAssembler:
             fill += np.bincount(key, minlength=fill.size)
 
     def finish(self) -> BlockSparse:
+        """The grid as host arrays; ``ShardSource.blocks`` places each
+        block on its own device of the mesh."""
         csc_rows, csc_vals, csr_cols, csr_vals = self._arrays
         return BlockSparse(
-            csc_rows=jnp.asarray(csc_rows), csc_vals=jnp.asarray(csc_vals),
-            csr_cols=jnp.asarray(csr_cols), csr_vals=jnp.asarray(csr_vals),
+            csc_rows=csc_rows, csc_vals=csc_vals,
+            csr_cols=csr_cols, csr_vals=csr_vals,
             shape=(self.n, self.d),
             padded=(self.n_loc * self.a, self.d_loc * self.b),
         )

@@ -51,7 +51,6 @@ from typing import Any, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.dp.accountant import em_log_weight_scale
@@ -150,9 +149,9 @@ def build_dist_fw(blocks_abs, mesh: Mesh, *, steps: int,
         alpha0 = jax.lax.psum(alpha_part, rows)
         return vbar0, qbar0, alpha0
 
-    setup_sm = shard_map(
+    setup_sm = jax.shard_map(
         setup_body, mesh=mesh, in_specs=(blocks_spec, P(rows)),
-        out_specs=(P(rows), P(rows), P("model")), check_rep=False)
+        out_specs=(P(rows), P(rows), P("model")), check_vma=False)
 
     # ---- scan: T iterations, (λ, em_scale, gap_tol, key) traced -----------
     # ``y_loc`` is the local row shard's labels — read only by label-coupled
@@ -278,11 +277,11 @@ def build_dist_fw(blocks_abs, mesh: Mesh, *, steps: int,
         return w_loc * w_m, gaps, coords, stop_step
 
     scalar = P()
-    scan_sm = shard_map(
+    scan_sm = jax.shard_map(
         scan_body, mesh=mesh,
         in_specs=(blocks_spec, P(rows), P(rows), P(rows), P("model"),
                   scalar, scalar, scalar, scalar),
-        out_specs=(P("model"), P(), P(), P()), check_rep=False)
+        out_specs=(P("model"), P(), P(), P()), check_vma=False)
 
     def whole(blocks, y_pad, lam, em_scale, gap_tol, key):
         return scan_sm(blocks, y_pad, *setup_sm(blocks, y_pad), lam, em_scale,

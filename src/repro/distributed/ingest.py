@@ -88,10 +88,18 @@ class ShardSource:
         return cls(shape=csr.shape, csr=csr)
 
     def blocks(self, a: int, b: int) -> BlockSparse:
+        """The (a × b) grid, each block resident on its device of the
+        ``jax_shard`` mesh (built and placed once per grid)."""
         key = (int(a), int(b))
         if key not in self._blocks:
             if self.store is not None:
-                self._blocks[key] = blocks_from_store(self.store, a, b)
+                host = blocks_from_store(self.store, a, b)
             else:
-                self._blocks[key] = build_block_sparse(self.csr, a, b)
+                host = build_block_sparse(self.csr, a, b)
+            import jax
+
+            from repro.core.solvers.jax_shard import make_shard_mesh
+            from repro.distributed.fw_shard import dist_fw_shardings
+            shardings, _ = dist_fw_shardings(host, make_shard_mesh(a, b))
+            self._blocks[key] = jax.device_put(host, shardings)
         return self._blocks[key]

@@ -1,22 +1,26 @@
-"""Public jit'd wrappers for the padded-ELL sparse mat-vec kernels.
+"""Padded-ELL sparse mat-vec: X·w and Xᵀ·q over a ``PaddedCSR``.
 
-``interpret`` defaults to True (this container is CPU-only; TPU is the
-target).  On a real TPU pass ``interpret=False`` — block shapes and the
-sequential-grid accumulation pattern are already TPU-legal.
+Semantics: ``indices``/``values`` are (N, K), each row padded to K lanes
+with ``index = 0, value = 0`` (inert in sums, safe to gather).
+
+  * ``ell_matvec``:  out[i]  = Σ_k values[i,k] · w[indices[i,k]]        → (N,)
+  * ``ell_rmatvec``: out[j] += Σ_{i,k: indices[i,k]=j} values[i,k]·q[i] → (D,)
+
+Both run as XLA gather / scatter-add on every platform: Mosaic has no
+lowering for either, so a Pallas form could not compile for the TPU.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 
 from repro.core.sparse.formats import PaddedCSR
-from repro.kernels.spmv.kernel import ell_matvec_pallas, ell_rmatvec_pallas
 
 
-def ell_matvec(X: PaddedCSR, w: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
-    """X · w for a PaddedCSR design matrix via the Pallas row-tile kernel."""
-    return ell_matvec_pallas(X.indices, X.values, w, interpret=interpret)
+def ell_matvec(X: PaddedCSR, w: jnp.ndarray) -> jnp.ndarray:
+    """X · w — gather + row reduction."""
+    return X.matvec(w)
 
 
-def ell_rmatvec(X: PaddedCSR, q: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
-    """Xᵀ · q via the Pallas sequential scatter-accumulate kernel."""
-    return ell_rmatvec_pallas(X.indices, X.values, q, X.shape[1], interpret=interpret)
+def ell_rmatvec(X: PaddedCSR, q: jnp.ndarray) -> jnp.ndarray:
+    """Xᵀ · q — scatter-add over the padded lanes."""
+    return X.rmatvec(q)

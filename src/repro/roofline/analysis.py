@@ -1,9 +1,9 @@
 """Three-term roofline model over dry-run artifacts (deliverable g).
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI (bidirectional ⇒ 2× per-link bytes/s in the collective
-term; a 2-D torus gives each chip multiple links, but we charge the single
-busiest link — conservative).
+Hardware constants come from ``CHIP_PEAKS``, keyed by the device kind JAX
+reports (``jax.devices()[0].device_kind``); a device that is not in the
+table is an error, never a default.  The collective term charges the single
+busiest link, bidirectional (2× per-link bytes/s) — conservative.
 
 ``compiled.cost_analysis()`` counts a while-loop body **once**; scanned
 transformers execute theirs L (layers) × M (microbatches) times.  The
@@ -26,18 +26,40 @@ between vmapped and sequential sweep execution.
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, Optional
 
-# TPU v5e
-PEAK_FLOPS = 197e12        # bf16 FLOP/s per chip
-HBM_BW = 819e9             # bytes/s per chip
-ICI_BW = 50e9              # bytes/s per link per direction
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks, in the keywords ``roofline_terms`` takes."""
+
+    peak_flops: float    # bf16 FLOP/s
+    hbm_bw: float        # HBM bytes/s
+    ici_bw: float        # interconnect bytes/s per link per direction
+
+
+# Source: Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 16 GB
+# of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (over four
+# links: 50 GB/s each).
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(peak_flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """The published peaks of ``device_kind``; raises for any other chip."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device_kind!r}; add them "
+            f"to CHIP_PEAKS (known: {', '.join(sorted(CHIP_PEAKS))})") from None
 
 
 def roofline_terms(*, flops: float, bytes_accessed: float,
-                   collective_bytes: float, chips: int,
-                   peak_flops: float = PEAK_FLOPS, hbm_bw: float = HBM_BW,
-                   ici_bw: float = ICI_BW) -> Dict[str, float]:
+                   collective_bytes: float, chips: int, peak_flops: float,
+                   hbm_bw: float, ici_bw: float) -> Dict[str, float]:
     """The three roofline times (seconds) + dominant bottleneck.
 
     ``flops``/``bytes_accessed`` are per-device (that's what

@@ -49,6 +49,8 @@ import math
 import time
 from typing import Dict, List, Mapping, Optional
 
+import jax
+
 from repro import obs
 from repro.core.dp.accountant import PrivacyAccountant, per_step_epsilon
 from repro.core.solvers.batched import group_key, solve_many
@@ -367,6 +369,11 @@ class FitService:
                 results = solve_many(self._source, self.y,
                                      [r.config for r in batch],
                                      prepared=self._coerced)
+                # device work is dispatched asynchronously: a request is
+                # done (and its latency stamped) when its result exists, and
+                # a device failure must land in this batch's handler
+                jax.block_until_ready(
+                    [getattr(r, "results", r) for r in results])
         except Exception as e:  # noqa: BLE001 — one bad batch must not
             # strand the rest of the queue.  The charged budget is NOT
             # refunded: admission cannot prove how far the mechanism got

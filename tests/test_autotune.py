@@ -101,13 +101,34 @@ def test_probe_parity_gates_a_corrupted_layout(problem, padded):
     pcsr, pcsc = padded
     width = candidate_widths(pcsc)[-1]
     good = tiered_from_padded(pcsc, width)
-    assert probe_parity(pcsr, pcsc, good, y, loss="logistic", interpret=True,
-                        steps=8)
+    assert probe_parity(pcsr, pcsc, good, y, loss="logistic", steps=8)
     bad = dataclasses.replace(
         good, values=jnp.asarray(np.asarray(good.values) * 1.5),
         heavy_values=jnp.asarray(np.asarray(good.heavy_values) * 1.5))
-    assert not probe_parity(pcsr, pcsc, bad, y, loss="logistic",
-                            interpret=True, steps=8)
+    assert not probe_parity(pcsr, pcsc, bad, y, loss="logistic", steps=8)
+
+
+def test_probe_parity_refuses_chunked_driver_drift(problem, padded,
+                                                   monkeypatch):
+    """The gate covers the chunked driver too: a tier that matches the flat
+    layout in the whole-run scan but drifts by one ulp in a chunked run's
+    gap trace (the step compiles in a different context there) is
+    refused."""
+    import repro.core.solvers.autotune as at
+    X, y = problem
+    pcsr, pcsc = padded
+    tier = tiered_from_padded(pcsc, candidate_widths(pcsc)[-1])
+    assert probe_parity(pcsr, pcsc, tier, y, loss="logistic", steps=8)
+    real = at._chunked_once
+
+    def drifting(pcsr_, csc, *args, **kwargs):
+        w, gaps, coords = real(pcsr_, csc, *args, **kwargs)
+        if csc is tier:
+            gaps = gaps.at[-1].set(np.nextafter(gaps[-1], np.inf))
+        return w, gaps, coords
+
+    monkeypatch.setattr(at, "_chunked_once", drifting)
+    assert not probe_parity(pcsr, pcsc, tier, y, loss="logistic", steps=8)
 
 
 # ---------------------------------------------------------------------------
@@ -188,9 +209,17 @@ def test_warm_open_replays_record_without_research(store, monkeypatch):
                            platform=rec.platform) == rec
 
 
-def test_force_retunes_and_content_hash_guards(store, tmp_path):
+def test_force_retunes_and_content_hash_guards(store, tmp_path,
+                                              monkeypatch):
+    # The scan's cost follows a column's nnz, not the layout's width, so a
+    # tier and the flat table time within noise of each other on the CPU;
+    # a per-iteration cost fixed by the layout's width keeps the winner
+    # independent of the machine's load.
+    import repro.core.solvers.autotune as at
+    monkeypatch.setattr(at, "_time_layout",
+                        lambda pcsr, csc, *a, **k: float(csc.indices.shape[1]))
     rec = autotune(store, steps=6, probe_steps=8)
-    # force=True ignores the cache (timings may differ; knobs are stable)
+    # force=True ignores the cache and searches again to the same knobs
     rec2 = autotune(store, steps=6, probe_steps=8, force=True)
     assert rec2.ell_width == rec.ell_width
     # a record for different content must not replay

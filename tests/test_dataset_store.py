@@ -209,17 +209,17 @@ def test_setup_cache_persists_and_replays_bitwise(problem, store):
     from repro.core.solvers.jax_sparse import fw_setup_jit
     X, y = problem
     prep = store.prepared()
-    s1 = prep.setup_for(y, "logistic", True)
-    path = store._setup_cache_path("logistic", True)
+    s1 = prep.setup_for(y, "logistic")
+    path = store._setup_cache_path("logistic")
     assert os.path.exists(path)
     # a fresh open must hit the disk cache and replay identical bits
     st2 = DatasetStore.open(store.root)
-    s2 = st2.prepared().setup_for(y, "logistic", True)
+    s2 = st2.prepared().setup_for(y, "logistic")
     for a, b in zip(s1, s2):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # and the cache content equals a direct fw_setup on the padded pair
     ref = fw_setup_jit(prep.pcsr, jnp.asarray(y, jnp.float32),
-                       loss="logistic", interpret=True)
+                       loss="logistic")
     for a, b in zip(s1, ref):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -242,9 +242,9 @@ def test_padded_cache_replays_bitwise(store):
 def test_setup_cache_ignores_foreign_labels(problem, store):
     X, y = problem
     prep = store.prepared()
-    cached = prep.setup_for(y, "logistic", True)
+    cached = prep.setup_for(y, "logistic")
     flipped = 1.0 - y
-    fresh = prep.setup_for(flipped, "logistic", True)
+    fresh = prep.setup_for(flipped, "logistic")
     assert not np.array_equal(np.asarray(cached[2]), np.asarray(fresh[2]))
 
 
@@ -255,7 +255,7 @@ def test_setup_streamed_matches_kernel_setup(problem, store):
     X, y = problem
     v0, q0, a0 = store.setup_streamed("logistic")
     ref = fw_setup_jit(store.prepared().pcsr, jnp.asarray(y, jnp.float32),
-                       loss="logistic", interpret=True)
+                       loss="logistic")
     np.testing.assert_allclose(np.asarray(a0), np.asarray(ref[2]), atol=1e-5)
     np.testing.assert_allclose(np.asarray(q0), np.asarray(ref[1]), atol=1e-6)
     assert float(np.abs(np.asarray(v0)).max()) == 0.0
@@ -335,7 +335,7 @@ def test_setup_streamed_matches_kernel_setup_label_coupled(problem, store):
     X, y = problem
     v0, q0, a0 = store.setup_streamed("huber")
     ref = fw_setup_jit(store.prepared().pcsr, jnp.asarray(y, jnp.float32),
-                       loss="huber", interpret=True)
+                       loss="huber")
     np.testing.assert_allclose(np.asarray(a0), np.asarray(ref[2]), atol=1e-5)
     np.testing.assert_allclose(np.asarray(q0), np.asarray(ref[1]), atol=1e-6)
     assert float(np.abs(np.asarray(v0)).max()) == 0.0

@@ -6,10 +6,14 @@ The non-private solve goes through the *registry* (``backend="jax_shard"``,
 build → setup/scan programs — is exercised on a genuinely sharded mesh, not
 just the 1×1 parity harness of test_jax_shard.py."""
 import json
+import os
+import pathlib
 import subprocess
 import sys
 
 import pytest
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
 
 SCRIPT = r"""
 import os
@@ -23,11 +27,8 @@ from repro.distributed.fw_shard import DistFWConfig, distributed_fw
 
 X, y, _ = make_sparse_classification(n=120, d=400, nnz_per_row=10,
                                      informative=15, seed=5)
-if hasattr(jax.sharding, "AxisType"):
-    mesh = jax.make_mesh((2, 2), ("data", "model"),
-                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
-else:
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 blocks = build_block_sparse(X, 2, 2)
 y_pad = jnp.zeros(blocks.padded[0], jnp.float32).at[:len(y)].set(
     jnp.asarray(y, jnp.float32))
@@ -64,10 +65,9 @@ print("RESULT" + json.dumps(out))
 
 @pytest.fixture(scope="module")
 def dist_result():
+    env = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
     proc = subprocess.run([sys.executable, "-c", SCRIPT], capture_output=True,
-                          text=True, timeout=900,
-                          env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-                          cwd="/root/repo")
+                          text=True, timeout=900, env=env, cwd=ROOT)
     assert proc.returncode == 0, proc.stderr[-3000:]
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT")][0]
     return json.loads(line[len("RESULT"):])

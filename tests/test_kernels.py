@@ -1,18 +1,26 @@
-"""Per-kernel allclose vs the pure-jnp oracles, swept over shapes/dtypes
-(interpret mode — CPU container, TPU target)."""
+"""Every kernel entry point against an oracle, swept over shapes/dtypes.
+
+spmv and coord_update are checked against float64 numpy oracles; the
+bsls_draw Pallas kernel (interpreted on the CPU) against its pure-jnp
+reference and the exponential mechanism's law."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.sparse.formats import PaddedCSR
 from repro.kernels.bsls_draw.ops import two_level_draw
 from repro.kernels.bsls_draw.ref import two_level_draw_ref
 from repro.kernels.coord_update.ops import coord_update
-from repro.kernels.coord_update.ref import coord_update_ref
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.spmv.kernel import ell_matvec_pallas, ell_rmatvec_pallas
-from repro.kernels.spmv.ref import ell_matvec_ref, ell_rmatvec_ref
+from repro.kernels.spmv.ops import ell_matvec, ell_rmatvec
+
+
+def _ell(idx, val, d):
+    n, k = idx.shape
+    return PaddedCSR(jnp.asarray(idx, jnp.int32), val,
+                     jnp.full((n,), k, jnp.int32), (n, d))
 
 
 # ---------------------------------------------------------------------------
@@ -22,39 +30,44 @@ from repro.kernels.spmv.ref import ell_matvec_ref, ell_rmatvec_ref
 @pytest.mark.parametrize("n,k,d", [(64, 5, 40), (300, 17, 1000), (1000, 64, 500)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_ell_matvec(n, k, d, dtype, rng):
-    idx = jnp.asarray(rng.integers(0, d, (n, k)), jnp.int32)
+    idx = rng.integers(0, d, (n, k))
     val = jnp.asarray(rng.normal(size=(n, k)), dtype)
     w = jnp.asarray(rng.normal(size=d), dtype)
-    got = ell_matvec_pallas(idx, val, w)
-    want = ell_matvec_ref(idx, val, w)
+    got = ell_matvec(_ell(idx, val, d), w)
+    val64 = np.asarray(val, np.float64)
+    want = (val64 * np.asarray(w, np.float64)[idx]).sum(axis=1)
     tol = 1e-5 if dtype == jnp.float32 else 0.15
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), rtol=tol, atol=tol)
+    np.testing.assert_allclose(np.asarray(got, np.float64), want, rtol=tol,
+                               atol=tol)
 
 
 @pytest.mark.parametrize("n,k,d", [(64, 5, 40), (512, 16, 300), (100, 33, 2000)])
 def test_ell_rmatvec(n, k, d, rng):
-    idx = jnp.asarray(rng.integers(0, d, (n, k)), jnp.int32)
-    val = jnp.asarray(rng.normal(size=(n, k)), jnp.float32)
-    q = jnp.asarray(rng.normal(size=n), jnp.float32)
-    got = ell_rmatvec_pallas(idx, val, q, d)
-    want = ell_rmatvec_ref(idx, val, q, d)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+    idx = rng.integers(0, d, (n, k))
+    val = rng.normal(size=(n, k)).astype(np.float32)
+    q = rng.normal(size=n).astype(np.float32)
+    got = ell_rmatvec(_ell(idx, jnp.asarray(val), d), jnp.asarray(q))
+    want = np.zeros(d)
+    np.add.at(want, idx.reshape(-1),
+              (val.astype(np.float64) * q.astype(np.float64)[:, None])
+              .reshape(-1))
+    np.testing.assert_allclose(np.asarray(got, np.float64), want, rtol=1e-5,
                                atol=1e-5)
 
 
 def test_spmv_vs_padded_csr(tiny_problem):
-    """Kernel path ≡ the PaddedCSR ops used by fw_dense."""
+    """The padded entry points ≡ the exact host CSR products (float64)."""
     from repro.core.sparse.formats import host_to_padded
-    from repro.kernels.spmv.ops import ell_matvec, ell_rmatvec
     X, y, _ = tiny_problem
     pcsr, _ = host_to_padded(X)
-    w = jnp.asarray(np.random.default_rng(1).normal(size=X.shape[1]), jnp.float32)
-    np.testing.assert_allclose(np.asarray(ell_matvec(pcsr, w)),
-                               np.asarray(pcsr.matvec(w)), rtol=1e-5, atol=1e-5)
-    q = jnp.asarray(np.random.default_rng(2).normal(size=X.shape[0]), jnp.float32)
-    np.testing.assert_allclose(np.asarray(ell_rmatvec(pcsr, q)),
-                               np.asarray(pcsr.rmatvec(q)), rtol=1e-4, atol=1e-4)
+    w = np.random.default_rng(1).normal(size=X.shape[1])
+    np.testing.assert_allclose(
+        np.asarray(ell_matvec(pcsr, jnp.asarray(w, jnp.float32))),
+        X.matvec(w), rtol=1e-5, atol=1e-5)
+    q = np.random.default_rng(2).normal(size=X.shape[0])
+    np.testing.assert_allclose(
+        np.asarray(ell_rmatvec(pcsr, jnp.asarray(q, jnp.float32))),
+        X.rmatvec(q), rtol=1e-4, atol=1e-4)
 
 
 # ---------------------------------------------------------------------------
@@ -91,28 +104,48 @@ def test_two_level_draw_distribution(rng):
 # coord_update
 # ---------------------------------------------------------------------------
 
+def _coord_update_np(vbar, qbar, alpha, w, rows, x_col, mask, row_idx,
+                     row_val, *, eta, d_tilde, w_m, inv_n):
+    """Alg-2 lines 22-28 in float64 numpy, one lane at a time."""
+    vbar, qbar, alpha = (np.array(a, np.float64) for a in (vbar, qbar, alpha))
+    w = np.asarray(w, np.float64)
+    row_val = np.asarray(row_val, np.float64)
+    g_delta = 0.0
+    for c in np.flatnonzero(mask):
+        vbar[rows[c]] += eta * d_tilde * x_col[c] / w_m
+    for c in np.flatnonzero(mask):
+        r = rows[c]
+        gamma = 1.0 / (1.0 + np.exp(-w_m * vbar[r])) - qbar[r]
+        qbar[r] += gamma
+        np.add.at(alpha, row_idx[c], gamma * inv_n * row_val[c])
+        g_delta += w_m * gamma * inv_n * (row_val[c] @ w[row_idx[c]])
+    return vbar, qbar, alpha, g_delta
+
+
 @pytest.mark.parametrize("n,d,kc,kr", [(100, 300, 17, 7), (200, 500, 37, 11),
                                        (50, 64, 5, 3), (400, 1000, 130, 20)])
 def test_coord_update_matches_ref(n, d, kc, kr, rng):
-    vbar = jnp.asarray(rng.normal(size=n), jnp.float32)
-    qbar = jnp.asarray(jax.nn.sigmoid(vbar))
-    alpha = jnp.asarray(rng.normal(size=d), jnp.float32)
-    w = jnp.asarray(rng.normal(size=d) * 0.1, jnp.float32)
-    rows = jnp.asarray(rng.choice(n, kc, replace=False), jnp.int32)
-    x_col = jnp.asarray(rng.normal(size=kc), jnp.float32)
-    mask = jnp.asarray(rng.random(kc) < 0.8)
-    x_col = jnp.where(mask, x_col, 0.0)
-    row_idx = jnp.asarray(rng.integers(0, d, (kc, kr)), jnp.int32)
-    row_val = jnp.asarray(rng.normal(size=(kc, kr)), jnp.float32)
+    vbar = rng.normal(size=n)
+    qbar = 1.0 / (1.0 + np.exp(-vbar))
+    alpha = rng.normal(size=d)
+    w = rng.normal(size=d) * 0.1
+    rows = rng.choice(n, kc, replace=False)
+    mask = rng.random(kc) < 0.8
+    x_col = np.where(mask, rng.normal(size=kc), 0.0)
+    row_idx = rng.integers(0, d, (kc, kr))
+    row_val = rng.normal(size=(kc, kr))
     kw = dict(eta=0.05, d_tilde=-8.0, w_m=0.9, inv_n=1.0 / n)
-    ref = coord_update_ref(vbar, qbar, alpha, w, rows, x_col, mask,
+    f32 = lambda a: jnp.asarray(a, jnp.float32)
+    ref = _coord_update_np(vbar, qbar, alpha, w, rows, x_col, mask,
                            row_idx, row_val, **kw)
-    got = coord_update(vbar, qbar, alpha, w, rows, x_col, mask,
-                       row_idx, row_val, **kw)
+    got = coord_update(f32(vbar), f32(qbar), f32(alpha), f32(w),
+                       jnp.asarray(rows, jnp.int32), f32(x_col),
+                       jnp.asarray(mask), jnp.asarray(row_idx, jnp.int32),
+                       f32(row_val), **kw)
     for name, a, b in zip(("vbar", "qbar", "alpha"), ref[:3], got[:3]):
-        np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=1e-5,
+        np.testing.assert_allclose(np.asarray(b, np.float64), a, rtol=1e-5,
                                    atol=1e-6, err_msg=name)
-    assert float(got[3]) == pytest.approx(float(ref[3]), abs=1e-5)
+    assert float(got[3]) == pytest.approx(ref[3], abs=1e-5)
 
 
 # ---------------------------------------------------------------------------

@@ -49,9 +49,12 @@ def test_collective_bytes_nested_multiplies_trip_count():
 def test_roofline_terms_math():
     """Inputs are PER-DEVICE (verified: cost_analysis() of an SPMD module
     reports the per-device program), so the per-chip rates divide directly."""
-    from repro.roofline.analysis import roofline_terms
+    import dataclasses
+
+    from repro.roofline.analysis import chip_peaks, roofline_terms
     terms = roofline_terms(flops=1.0e13, bytes_accessed=1.0e12,
-                           collective_bytes=1.0e10, chips=256)
+                           collective_bytes=1.0e10, chips=256,
+                           **dataclasses.asdict(chip_peaks("TPU v5 lite")))
     assert terms["t_compute_s"] == pytest.approx(1.0e13 / 197e12)
     assert terms["t_memory_s"] == pytest.approx(1.0e12 / 819e9)
     assert terms["t_collective_s"] == pytest.approx(1.0e10 / (2 * 50e9))

