@@ -229,9 +229,15 @@ def _group_stats(pcsr, pcsc):
 
 
 def _solve_jax_sparse_group(
-    data, y, configs: Sequence[FWConfig]
+    data, y, configs: Sequence[FWConfig], sp
 ) -> List[FWResult]:
-    """Run a compatible fixed-T config group as one vmap-over-configs scan."""
+    """Run a compatible fixed-T config group as one vmap-over-configs scan.
+
+    With a collector active the ``group.vmap`` span ``sp`` records the row
+    chunks of the lanes' coordinate updates, counted when the collector
+    settles: ``lane_chunks`` summed over lanes and steps (the useful work),
+    and ``run_chunks`` summed over steps of the lanes' maximum (the batched
+    loop runs until its last lane is done, the others masked)."""
     c0 = configs[0]
     pcsr, pcsc, setup, sc = _group_context(data, y, configs)
     private = c0.queue == "two_level"
@@ -245,6 +251,16 @@ def _solve_jax_sparse_group(
                 _group_stats(pcsr, pcsc),
                 (time.perf_counter() - t0) / (c0.steps * len(configs)),
                 loss=c0.loss)
+    if obs.enabled():
+        from repro.core.solvers.jax_sparse import TILE_ROWS, step_chunks
+        col_nnz = pcsc.nnz
+
+        def counts():
+            per = step_chunks(col_nnz, coords)           # (lanes, T)
+            return {"lane_chunks": int(per.sum()),
+                    "run_chunks": int(per.max(axis=0).sum())}
+        sp.set(tile_rows=TILE_ROWS)
+        sp.defer(counts)
     return [FWResult(w=w[i], gaps=gaps[i], coords=coords[i],
                      losses=jnp.zeros_like(gaps[i]), stop_step=c0.steps,
                      stop_reason=STOP_MAX_STEPS)
@@ -557,8 +573,8 @@ def _run_jax_sparse_group(data, y, member_cfgs: Sequence[FWConfig],
     if early:
         with obs.span("group.cohort", size=len(member_cfgs)):
             return _solve_jax_sparse_group_cohort(data, y, member_cfgs)
-    with obs.span("group.vmap", size=len(member_cfgs)):
-        return _solve_jax_sparse_group(data, y, member_cfgs)
+    with obs.span("group.vmap", size=len(member_cfgs)) as sp:
+        return _solve_jax_sparse_group(data, y, member_cfgs, sp)
 
 
 def solve_many(X, y=None, configs: Sequence[FWConfig] = (), *,

@@ -48,10 +48,12 @@ prefix, which is what the early-stopping parity tests pin.
 """
 from __future__ import annotations
 
+import time
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.dp.accountant import em_log_weight_scale
 from repro.core.losses import get_loss
@@ -68,6 +70,15 @@ from repro.kernels.spmv.ops import ell_rmatvec
 
 # Rows of column j's tile per coordinate-update chunk: one lane-width.
 TILE_ROWS = 128
+
+
+def step_chunks(col_nnz, coords) -> np.ndarray:
+    """Row chunks the coordinate update ran at each step of a fixed-T scan's
+    ``coords`` (any shape, e.g. (lanes, T)): ⌈nnz_j / TILE_ROWS⌉ for the
+    step's coordinate j.  ``col_nnz`` is the layout's true per-column
+    counts (``nnz`` of the flat and the tiered layout).  Host-side
+    telemetry: it copies both arrays to the host."""
+    return -(-np.asarray(col_nnz)[np.asarray(coords)] // TILE_ROWS)
 
 
 def _pad_rows(a: jnp.ndarray) -> jnp.ndarray:
@@ -90,15 +101,16 @@ def fw_setup(
     n = pcsr.shape[0]
     dtype = pcsr.values.dtype
     obj = get_loss(loss)
-    vbar0 = jnp.zeros(n, dtype)
-    if obj.separable:
-        h = obj.split_grad
-        ybar = ell_rmatvec(pcsr, y) / n
-        qbar0 = h(vbar0)
-        alpha0 = ell_rmatvec(pcsr, qbar0) / n - ybar
-    else:
-        qbar0 = obj.grad(vbar0, y)
-        alpha0 = ell_rmatvec(pcsr, qbar0) / n
+    with jax.named_scope("fw.setup"):
+        vbar0 = jnp.zeros(n, dtype)
+        if obj.separable:
+            h = obj.split_grad
+            ybar = ell_rmatvec(pcsr, y) / n
+            qbar0 = h(vbar0)
+            alpha0 = ell_rmatvec(pcsr, qbar0) / n - ybar
+        else:
+            qbar0 = obj.grad(vbar0, y)
+            alpha0 = ell_rmatvec(pcsr, qbar0) / n
     return vbar0, qbar0, alpha0
 
 
@@ -182,23 +194,25 @@ def fw_scan_chunk(
         (w, w_m, g_tilde, vbar, qbar, alpha, sampler, key,
          done, stop_at) = carry
         t = (t0 + i).astype(dtype)
-        key_next, sel_key = jax.random.split(key)
         # ---- line 15: select coordinate -------------------------------------
-        if private:
-            j = two_level_draw(sampler.c, sampler.v, sel_key)
-            sampler_after_sel = sampler
-        else:
-            j, sampler_after_sel = ga_get_next(sampler)
-        j = jnp.minimum(j, d - 1)
-        a_j = alpha[j]
+        with jax.named_scope("fw.select"):
+            key_next, sel_key = jax.random.split(key)
+            if private:
+                j = two_level_draw(sampler.c, sampler.v, sel_key)
+                sampler_after_sel = sampler
+            else:
+                j, sampler_after_sel = ga_get_next(sampler)
+            j = jnp.minimum(j, d - 1)
         # ---- lines 16-21 -----------------------------------------------------
-        d_tilde = -lam * jnp.sign(a_j)
-        d_tilde = jnp.where(a_j == 0, lam, d_tilde)
-        gap = g_tilde - d_tilde * a_j
-        eta = 2.0 / (t + 2.0)
-        w_m = w_m * (1.0 - eta)
-        w = w.at[j].add(eta * d_tilde / w_m)
-        g_tilde = g_tilde * (1.0 - eta) + eta * d_tilde * a_j
+        with jax.named_scope("fw.step"):
+            a_j = alpha[j]
+            d_tilde = -lam * jnp.sign(a_j)
+            d_tilde = jnp.where(a_j == 0, lam, d_tilde)
+            gap = g_tilde - d_tilde * a_j
+            eta = 2.0 / (t + 2.0)
+            w_m = w_m * (1.0 - eta)
+            w = w.at[j].add(eta * d_tilde / w_m)
+            g_tilde = g_tilde * (1.0 - eta) + eta * d_tilde * a_j
         # ---- lines 22-28: the coordinate update over column j's tile -------
         def apply_tile(col):
             """Lines 22-29 on column j's tile, ``TILE_ROWS`` rows at a
@@ -209,27 +223,31 @@ def fw_scan_chunk(
             not every step's cost.  Rows are distinct, so the chunks' v̄/q̄
             updates commute; a coordinate touched by several chunks is
             refreshed after each, the last time with its final α."""
-            rows, xvals, mask = (_pad_rows(a) for a in col)   # (K,)
-            n_chunks = (jnp.sum(mask) + TILE_ROWS - 1) // TILE_ROWS
+            with jax.named_scope("fw.coord_update"):
+                rows, xvals, mask = (_pad_rows(a) for a in col())   # (K,)
+                n_chunks = (jnp.sum(mask) + TILE_ROWS - 1) // TILE_ROWS
 
             def chunk(c, state):
                 vbar, qbar, alpha, g_tilde, sampler = state
-                r, x, m = (jax.lax.dynamic_slice_in_dim(
-                    a, c * TILE_ROWS, TILE_ROWS) for a in (rows, xvals, mask))
-                row_idx = pcsr.indices[r]                # (C, Kr)
-                row_val = pcsr.values[r]                 # (C, Kr) — 0 at padding
-                y_col = None if obj.separable else y[r]
-                vbar, qbar, alpha, g_c = coord_update(
-                    vbar, qbar, alpha, w, r, x, m, row_idx, row_val,
-                    eta=eta, d_tilde=d_tilde, w_m=w_m, inv_n=inv_n,
-                    loss=loss, y_col=y_col)
+                with jax.named_scope("fw.coord_update"):
+                    r, x, m = (jax.lax.dynamic_slice_in_dim(
+                        a, c * TILE_ROWS, TILE_ROWS)
+                        for a in (rows, xvals, mask))
+                    row_idx = pcsr.indices[r]       # (C, Kr)
+                    row_val = pcsr.values[r]        # (C, Kr) — 0 at padding
+                    y_col = None if obj.separable else y[r]
+                    vbar, qbar, alpha, g_c = coord_update(
+                        vbar, qbar, alpha, w, r, x, m, row_idx, row_val,
+                        eta=eta, d_tilde=d_tilde, w_m=w_m, inv_n=inv_n,
+                        loss=loss, y_col=y_col)
                 # line 29: refresh queue priorities for touched coordinates
-                flat_idx = row_idx.reshape(-1)
-                fresh = jnp.abs(alpha[flat_idx]) * (em_scale if private
-                                                    else 1.0)
-                refresh = tl_update if private else ga_update
-                return (vbar, qbar, alpha, g_tilde + g_c,
-                        refresh(sampler, flat_idx, fresh))
+                with jax.named_scope("fw.queue_refresh"):
+                    flat_idx = row_idx.reshape(-1)
+                    fresh = jnp.abs(alpha[flat_idx]) * (em_scale if private
+                                                        else 1.0)
+                    refresh = tl_update if private else ga_update
+                    sampler = refresh(sampler, flat_idx, fresh)
+                return vbar, qbar, alpha, g_tilde + g_c, sampler
 
             return jax.lax.fori_loop(
                 0, n_chunks, chunk,
@@ -240,26 +258,28 @@ def fw_scan_chunk(
             # tile, everything else the narrow one — same sums, fewer lanes
             vbar, qbar, alpha, g_tilde, sampler = jax.lax.cond(
                 pcsc.is_heavy(j),
-                lambda: apply_tile(pcsc.col_heavy(j)),
-                lambda: apply_tile(pcsc.col_light(j)))
+                lambda: apply_tile(lambda: pcsc.col_heavy(j)),
+                lambda: apply_tile(lambda: pcsc.col_light(j)))
         else:
-            vbar, qbar, alpha, g_tilde, sampler = apply_tile(pcsc.col(j))
+            vbar, qbar, alpha, g_tilde, sampler = apply_tile(
+                lambda: pcsc.col(j))
         new = FWCarry(w, w_m, g_tilde, vbar, qbar, alpha, sampler, key_next,
                       done, stop_at)
         if not early_stop:
             return new, (gap, j.astype(jnp.int32))
         # ---- §9 masked stopping: freeze frames once the certificate lands ---
-        newly = jnp.logical_and(~done, jnp.logical_and(gap_tol > 0,
-                                                       gap <= gap_tol))
-        frozen = carry._replace(
-            done=jnp.logical_or(done, newly),
-            stop_at=jnp.where(newly, t0 + i, stop_at))
-        merged = jax.tree_util.tree_map(
-            lambda old, fresh_leaf: jnp.where(done, old, fresh_leaf),
-            frozen,
-            new._replace(done=frozen.done, stop_at=frozen.stop_at))
-        out_gap = jnp.where(done, jnp.asarray(0.0, dtype), gap)
-        out_j = jnp.where(done, -1, j.astype(jnp.int32))
+        with jax.named_scope("fw.stop_mask"):
+            newly = jnp.logical_and(~done, jnp.logical_and(gap_tol > 0,
+                                                           gap <= gap_tol))
+            frozen = carry._replace(
+                done=jnp.logical_or(done, newly),
+                stop_at=jnp.where(newly, t0 + i, stop_at))
+            merged = jax.tree_util.tree_map(
+                lambda old, fresh_leaf: jnp.where(done, old, fresh_leaf),
+                frozen,
+                new._replace(done=frozen.done, stop_at=frozen.stop_at))
+            out_gap = jnp.where(done, jnp.asarray(0.0, dtype), gap)
+            out_j = jnp.where(done, -1, j.astype(jnp.int32))
         return merged, (out_gap, out_j)
 
     ts = jnp.arange(1, steps + 1, dtype=jnp.int32)
@@ -359,10 +379,6 @@ def _screened_chunked_fw(pcsr, pcsc, setup, config: FWConfig,
     cost book against the *current* geometry's stats, so the model sees the
     shrinking D, not the admission-time one.
     """
-    import time as _time
-
-    import numpy as np
-
     from repro.core.solvers.planner import data_stats, record_cost
     from repro.core.solvers.screening import (Screener, repack_carry,
                                               repack_pair)
@@ -389,13 +405,13 @@ def _screened_chunked_fw(pcsr, pcsc, setup, config: FWConfig,
 
     def advance(carry, t0, c):
         p, q = geom.operands
-        tw = _time.perf_counter()
+        tw = time.perf_counter()
         carry, out = fw_scan_chunk_jit(
             p, q, carry, config.lam, em_scale, config.gap_tol, t0, y,
             steps=c, loss=config.loss, private=private, early_stop=True)
         jax.block_until_ready(out[0])
         record_cost("jax_sparse", "sequential", platform, cur_stats(),
-                    (_time.perf_counter() - tw) / c, loss=config.loss)
+                    (time.perf_counter() - tw) / c, loss=config.loss)
         return carry, out
 
     def out_map(out, t0):
@@ -409,14 +425,14 @@ def _screened_chunked_fw(pcsr, pcsc, setup, config: FWConfig,
                           np.asarray(carry.w) != 0)
         if keep is None:
             return None
-        tw = _time.perf_counter()
+        tw = time.perf_counter()
         p2, q2 = repack_pair(*geom.operands, keep)
         carry2 = repack_carry(carry, keep, em_scale, private)
         pad2 = (q2.full_width if isinstance(q2, TieredCSC)
                 else int(q2.indices.shape[1]))
         geom.swap((p2, q2), p2.shape[1],
                   pad_row=int(p2.indices.shape[1]), pad_col=pad2)
-        info = scr.commit(keep, repack_seconds=_time.perf_counter() - tw)
+        info = scr.commit(keep, repack_seconds=time.perf_counter() - tw)
         return carry2, info
 
     carry, outs, stop_step, stop_reason = drive_chunks(
@@ -461,11 +477,24 @@ def jax_sparse_fw(
         return _chunked_fw(pcsr, pcsc, setup, config, em_scale, private,
                            y=y_scan)
     vbar0, qbar0, alpha0 = setup
-    with obs.span("solve.scan", steps=config.steps, private=private):
+    with obs.span("solve.scan", steps=config.steps, private=private) as sp:
+        t0 = time.perf_counter()
         w, gaps, coords, stop_step = fw_scan_jit(
             pcsr, pcsc, vbar0, qbar0, alpha0,
             config.lam, em_scale, jax.random.PRNGKey(config.seed), 0.0,
             y_scan, steps=config.steps, loss=config.loss, private=private)
-    return FWResult(w=w, gaps=gaps, coords=coords,
-                    losses=jnp.zeros_like(gaps), stop_step=config.steps,
-                    stop_reason=STOP_MAX_STEPS)
+        enqueue_s = time.perf_counter() - t0
+        # queued behind the scan before any wait, so the device never idles
+        # for its dispatch
+        losses = jnp.zeros_like(gaps)
+        if obs.enabled():
+            # with a collector only: the span ends with the device run;
+            # the row chunks the fit's coordinates ran are counted when
+            # the collector settles, off the measured region
+            sp.set(enqueue_s=enqueue_s, tile_rows=TILE_ROWS)
+            jax.block_until_ready((w, gaps, coords, losses))
+            col_nnz = pcsc.nnz
+            sp.defer(lambda: {
+                "chunks": int(step_chunks(col_nnz, coords).sum())})
+    return FWResult(w=w, gaps=gaps, coords=coords, losses=losses,
+                    stop_step=config.steps, stop_reason=STOP_MAX_STEPS)

@@ -2,10 +2,13 @@
 
 One rule governs everything in this package (DESIGN.md §12): telemetry is
 **host-side only and a true no-op when disabled**.  Instrumentation never
-enters traced/jitted code, never touches a PRNG key, and never changes a
-control-flow decision — solver iterates are bit-identical with telemetry
-on or off, which tier-1 tests pin on all five backends, private and
-non-private.
+touches a PRNG key and never changes a control-flow decision; traced code
+carries only ``jax.named_scope`` names, metadata that changes no
+computation — solver iterates are bit-identical with telemetry on or off,
+which tier-1 tests pin on all five backends, private and non-private.
+While a collector is active its spans are mirrored into the JAX profiler,
+and ``solve.scan`` waits for the device it times; host copies for work
+counts are deferred until the collector settles (``Span.defer``).
 
 Call sites use the module-level helpers, which cost one global read plus a
 ``None`` check when no collector is active::

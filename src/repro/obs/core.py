@@ -6,7 +6,14 @@ not.  Every module-level helper (``span``/``event``/``count``/``gauge``/
 the disabled path allocates nothing beyond the kwargs dict of the call
 itself, which is why instrumentation may sit on per-solve and per-chunk
 host paths (never per-iteration device paths; those are traced code and
-off-limits by the host-side-only rule, DESIGN.md §12).
+off-limits by the host-side-only rule, DESIGN.md §12 — traced code carries
+only ``jax.named_scope`` names, compile-time metadata that changes no
+computation).
+
+While a collector is active every span is mirrored into the JAX profiler
+as a ``jax.profiler.TraceAnnotation`` of its name, so a profiler trace
+holds the program's spans on the same clock as the device's operations.
+jax is imported when a collector is created, never when this module is.
 
 Event records are plain dicts, one of:
 
@@ -16,6 +23,8 @@ Event records are plain dicts, one of:
 ``ts`` is seconds since the collector was enabled (monotonic clock); spans
 are recorded at *close*, children before parents, so an ordered replay can
 rebuild the tree from ``id``/``parent`` alone (``repro.obs.report`` does).
+A span's deferred attributes (``Span.defer``) join its record when the
+collector settles: on ``disable``, at the end of a ``session``, on export.
 """
 from __future__ import annotations
 
@@ -33,7 +42,7 @@ _ACTIVE: Optional["Telemetry"] = None
 class Span:
     """One timed, attributed region; records an event when it exits."""
 
-    __slots__ = ("_tel", "name", "attrs", "id", "parent", "_t0")
+    __slots__ = ("_tel", "name", "attrs", "id", "parent", "_t0", "_mirror")
 
     def __init__(self, tel: "Telemetry", name: str, attrs: dict):
         self._tel = tel
@@ -42,11 +51,19 @@ class Span:
         self.id = 0
         self.parent = 0
         self._t0 = 0.0
+        self._mirror = None
 
     def set(self, **attrs) -> None:
         """Attach/overwrite attributes after entry (e.g. a resolved
         backend name known only mid-span)."""
         self.attrs.update(attrs)
+
+    def defer(self, fn) -> None:
+        """Attach the attributes ``fn()`` returns when the collector
+        settles (:meth:`Telemetry.settle`), not now: for host work, such as
+        a copy from the device, that must stay out of the measured region.
+        Until then the collector holds whatever ``fn`` refers to."""
+        self._tel._deferred.append((self.attrs, fn))
 
     def __enter__(self) -> "Span":
         tel = self._tel
@@ -54,12 +71,18 @@ class Span:
         stack = tel._stack_of()
         self.parent = stack[-1] if stack else 0
         stack.append(self.id)
+        if tel._annotation is not None:
+            self._mirror = tel._annotation(self.name)
+            self._mirror.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         tel = self._tel
         dur = time.perf_counter() - self._t0
+        if self._mirror is not None:
+            self._mirror.__exit__(exc_type, exc, tb)
+            self._mirror = None
         stack = tel._stack_of()
         if stack and stack[-1] == self.id:
             stack.pop()
@@ -81,6 +104,9 @@ class _NoopSpan:
     def set(self, **attrs) -> None:
         pass
 
+    def defer(self, fn) -> None:
+        pass
+
     def __enter__(self) -> "_NoopSpan":
         return self
 
@@ -89,6 +115,15 @@ class _NoopSpan:
 
 
 _NOOP_SPAN = _NoopSpan()
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or None where jax is not installed."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
 
 
 class Telemetry:
@@ -102,6 +137,8 @@ class Telemetry:
         self.metrics = MetricsRegistry()
         self._ids = itertools.count(1)
         self._local = threading.local()
+        self._annotation = _profiler_annotation()
+        self._deferred: List[tuple] = []
 
     def _stack_of(self) -> List[int]:
         stack = getattr(self._local, "spans", None)
@@ -118,6 +155,14 @@ class Telemetry:
     def event(self, name: str, **attrs) -> None:
         self.events.append({"ev": "event", "name": name,
                             "ts": round(self.now(), 6), "attrs": attrs})
+
+    def settle(self) -> None:
+        """Attach every deferred span attribute (:meth:`Span.defer`).
+        ``disable`` and the end of a ``session`` call it; call it before
+        reading ``events`` while the collector is still active."""
+        pending, self._deferred = self._deferred, []
+        for attrs, fn in pending:
+            attrs.update(fn())
 
 
 # ---------------------------------------------------------------------------
@@ -142,9 +187,11 @@ def enable(meta: Optional[dict] = None) -> Telemetry:
 
 
 def disable() -> Optional[Telemetry]:
-    """Uninstall the collector; returns it for export/inspection."""
+    """Uninstall the collector, settled; returns it for export/inspection."""
     global _ACTIVE
     tel, _ACTIVE = _ACTIVE, None
+    if tel is not None:
+        tel.settle()
     return tel
 
 
@@ -165,6 +212,7 @@ def session(jsonl_path: Optional[str] = None,
         yield tel
     finally:
         _ACTIVE = prev
+        tel.settle()
         if jsonl_path is not None:
             from repro.obs.exporters import write_jsonl
             write_jsonl(tel, jsonl_path)

@@ -18,7 +18,9 @@ _NAME_RE = re.compile(r"[^a-zA-Z0-9_]")
 
 
 def to_records(tel: Telemetry) -> List[dict]:
-    """The full run as ordered JSON-ready records (meta, events, metrics)."""
+    """The full run as ordered JSON-ready records (meta, events, metrics),
+    deferred span attributes settled."""
+    tel.settle()
     out = [{"ev": "meta", "wall_start_unix": tel.wall_start,
             "duration_s": round(tel.now(), 6), **tel.meta}]
     out.extend(tel.events)

@@ -137,7 +137,6 @@ class FitService:
         req.status = "queued"
         self.queue.append(req)
         obs.count("service.submitted", tenant=req.tenant)
-        obs.gauge("service.queue_depth", len(self.queue))
 
     def run(self) -> List[FitRequest]:
         """Drain the queue; returns every request (done/rejected/failed)."""
@@ -145,7 +144,6 @@ class FitService:
             admitted = [r for r in self.queue if self._admit(r)]
             rejected = [r for r in self.queue if r.status == "rejected"]
             self.queue = []
-            obs.gauge("service.queue_depth", 0)
             for batch in self._pack(admitted):
                 self._drain(batch)
         done = sorted(admitted + rejected, key=lambda r: r.uid)
@@ -363,6 +361,9 @@ class FitService:
 
     def _drain(self, batch: List[FitRequest]) -> None:
         t0 = time.time()
+        for req in batch:
+            obs.observe("service.wait_s", t0 - req.submitted_at,
+                        tenant=req.tenant)
         try:
             with obs.span("service.batch", size=len(batch),
                           backend=batch[0].config.backend):

@@ -189,6 +189,39 @@ def test_session_restores_previous_collector():
     assert obs.get() is None
 
 
+def test_span_defer_settles_on_disable_session_and_export():
+    """Deferred attributes join their span's record when the collector
+    settles, never before; the no-op span drops them."""
+    from repro.obs.exporters import to_records
+    calls = []
+
+    def later(n):
+        def fn():
+            calls.append(n)
+            return {"n": n}
+        return fn
+
+    tel = obs.enable()
+    with obs.span("a") as sp:
+        sp.defer(later(1))
+    (rec,) = tel.events
+    assert "n" not in rec["attrs"] and calls == []
+    assert obs.disable() is tel
+    assert rec["attrs"]["n"] == 1 and calls == [1]
+    with obs.session() as tel:
+        with obs.span("b") as sp:
+            sp.defer(later(2))
+        exported = [r for r in to_records(tel) if r.get("name") == "b"]
+        assert exported[0]["attrs"]["n"] == 2
+    with obs.session() as tel:
+        with obs.span("c") as sp:
+            sp.defer(later(3))
+    assert tel.events[0]["attrs"]["n"] == 3
+    with obs.span("d") as sp:                       # collector off
+        sp.defer(later(4))
+    assert calls == [1, 2, 3]
+
+
 def test_cache_counters_from_store(tmp_path, problem):
     """DatasetStore cache layers report hit/miss through obs."""
     from repro.data.store import DatasetStore
@@ -323,7 +356,8 @@ def test_fit_service_telemetry_acceptance(problem, tmp_path, monkeypatch):
     """ISSUE-8 acceptance: a full drain with telemetry enabled is (a) bit-
     identical to telemetry-off, (b) leaves a replayable ledger whose ε
     totals exactly match the accountants, (c) serves latency percentiles
-    and queue depth through stats() and both exporters."""
+    and queue depth through stats(), and queue wait through both
+    exporters."""
     from repro.core.solvers import planner
     from repro.obs.exporters import prometheus_text
     # pin the group execution mode: the §9 planner picks vmap vs sequential
@@ -367,18 +401,19 @@ def test_fit_service_telemetry_acceptance(problem, tmp_path, monkeypatch):
     # exactly one refusal (globex's 4th DP fit), attested in the trail
     assert disk["globex"]["refusals"] == 1
 
-    # (c) percentiles + queue depth via stats() and both exporters
+    # (c) percentiles, queue depth via stats(), queue wait via both
+    # exporters
     stats = svc_on.stats()
     lat = stats["latency_s"]
     assert lat["p50"] <= lat["p90"] <= lat["p99"] <= lat["max"]
     assert lat["p50"] > 0 and stats["queue_depth"] == 0
     assert "repro_service_latency_s" in prom
-    assert "repro_service_queue_depth" in prom
+    assert "repro_service_wait_s" in prom
     with open(events_path) as f:
         records = [json.loads(line) for line in f]
     metric_names = {r["name"] for r in records if r["ev"] == "metric"}
     assert "service.latency_s" in metric_names
-    assert "service.queue_depth" in metric_names
+    assert "service.wait_s" in metric_names
 
     # and the report CLI renders it all without error
     from repro.obs.report import render_path
