@@ -14,11 +14,13 @@ are O(√D) dense vector scans that the VPU runs at line rate; there is no
 data-dependent control flow, so the whole FW iteration stays inside one
 ``lax.scan``.
 
-State updates after a FW iteration touch ``S_c`` coordinates: we scatter the
-new log-weights and recompute the affected groups' log-sum-exps via a masked
-segment reduction — O(touched·M) lanes, exact (no incremental drift at all,
-which is *stronger* than the paper's O(1) updates; on TPU the vector rebuild
-is cheaper than scalar bookkeeping).
+State updates after a FW iteration: ``tl_rebuild`` rewrites the whole
+(G, M) table from the step's final |α| in one dense O(D) pass and takes a
+fresh log-sum-exp for every group whose row changed — exact (no incremental
+drift at all, which is *stronger* than the paper's O(1) updates; on TPU a
+dense vector pass per step is far cheaper than scattering the touched
+coordinates one index at a time).  ``tl_update`` is the scatter form, for
+callers that refresh a given index list.
 
 Law-exactness is by construction (law of total probability); tested by
 chi-square against ``exponential_mechanism_probs`` and against the faithful
@@ -74,11 +76,17 @@ def group_shape(d: int) -> Tuple[int, int]:
     return g, m
 
 
+def as_table(values: jnp.ndarray, g: int, m: int) -> jnp.ndarray:
+    """(D,) per-item values laid out as a (G, M) table: item j at slot
+    j = g·M + m, the slots past D NEG_INF padding."""
+    d = values.shape[0]
+    return jnp.pad(values, (0, g * m - d),
+                   constant_values=NEG_INF).reshape(g, m)
+
+
 def tl_init(log_weights: jnp.ndarray) -> TwoLevelSamplerState:
     d = log_weights.shape[0]
-    g, m = group_shape(d)
-    v = jnp.full((g * m,), NEG_INF, log_weights.dtype).at[:d].set(log_weights)
-    v = v.reshape(g, m)
+    v = as_table(log_weights, *group_shape(d))
     c = jax.scipy.special.logsumexp(v, axis=1)
     return TwoLevelSamplerState(v=v, c=c, d=d)
 
@@ -98,11 +106,11 @@ def tl_update(
     """Scatter new log-weights for ``idx`` (may contain duplicates/padding
     marked by idx >= d → dropped) and rebuild affected group sums exactly.
 
-    For simplicity and exactness we recompute all G group log-sum-exps; the
-    (G, M) logsumexp is one O(D) vector pass — only done once per FW
-    iteration, versus O(√D) per *draw*, so the iteration stays sub-linear in
-    wall terms that matter (the draw path) while updates remain a single
-    fused reduction.  The Pallas kernel variant (kernels/bsls) tiles this.
+    The scatter form of the refresh, for a step that names its touched
+    coordinates (``fw_jax``).  Every group log-sum-exp is recomputed in one
+    (G, M) pass; touched groups take theirs.  The ``jax_sparse`` scan
+    refreshes with ``tl_rebuild`` instead: the scatters here cost one
+    serialized slot per touched index, padding included.
     """
     m = state.group_size
     valid = idx < state.d
@@ -115,6 +123,23 @@ def tl_update(
     touched = jnp.zeros((state.groups,), bool).at[safe_idx // m].max(valid)
     c_new = jax.scipy.special.logsumexp(v, axis=1)
     c = jnp.where(touched, c_new, state.c)
+    return TwoLevelSamplerState(v=v, c=c, d=state.d)
+
+
+def tl_rebuild(state: TwoLevelSamplerState,
+               log_weights: jnp.ndarray) -> TwoLevelSamplerState:
+    """Rebuild the table densely from all D current log-weights.
+
+    One O(D) pass per FW step, after the coordinate update: entries whose
+    weight did not move are rewritten with the value they hold, so the table
+    equals the one ``tl_update`` leaves after scattering every touched
+    coordinate's final weight.  A group whose row changed takes its fresh
+    log-sum-exp; found by a dense row comparison, no scatter of group ids.
+    Every other group keeps its ``c``.
+    """
+    v = as_table(log_weights, *state.v.shape)
+    changed = jnp.any(v != state.v, axis=1)
+    c = jnp.where(changed, jax.scipy.special.logsumexp(v, axis=1), state.c)
     return TwoLevelSamplerState(v=v, c=c, d=state.d)
 
 

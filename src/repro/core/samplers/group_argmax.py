@@ -15,8 +15,11 @@ the returned index is the exact argmax.  Expected pops mirror the paper's
 ≤ 3‖w*‖₀ observation because only coordinates whose gradients grew carry
 fresh bounds.
 
-Updates are increase-only (O(1) scatter-max); decreases are ignored — that is
-what makes the bounds stale-but-safe.
+Bounds only ratchet upward on update; decreases are ignored — that is what
+makes them stale-but-safe.  ``ga_rebuild`` refreshes once per FW step with a
+dense O(D) pass (rewrite the priority table from |α|, ratchet each bound to
+its row's max); ``ga_update`` is the scatter form for callers that refresh
+a given index list.
 """
 from __future__ import annotations
 
@@ -27,7 +30,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
-NEG_INF = -1e30
+from repro.core.samplers.bsls_jax import NEG_INF, as_table
 
 
 @jax.tree_util.register_pytree_node_class
@@ -53,7 +56,7 @@ def ga_init(priorities: jnp.ndarray) -> GroupArgmaxState:
     d = priorities.shape[0]
     g = max(1, math.isqrt(max(d - 1, 0)) + 1)
     m = (d + g - 1) // g
-    p = jnp.full((g * m,), NEG_INF, priorities.dtype).at[:d].set(priorities).reshape(g, m)
+    p = as_table(priorities, g, m)
     return GroupArgmaxState(p=p, bound=jnp.max(p, axis=1), d=d)
 
 
@@ -65,6 +68,20 @@ def ga_update(state: GroupArgmaxState, idx: jnp.ndarray, priorities: jnp.ndarray
     vals = jnp.where(valid, priorities, state.p.reshape(-1)[safe_idx])
     p = state.p.reshape(-1).at[safe_idx].set(vals).reshape(state.p.shape)
     bound = state.bound.at[safe_idx // m].max(jnp.where(valid, vals, NEG_INF))
+    return GroupArgmaxState(p=p, bound=bound, d=state.d)
+
+
+def ga_rebuild(state: GroupArgmaxState,
+               priorities: jnp.ndarray) -> GroupArgmaxState:
+    """Rewrite the table densely from all D current priorities and ratchet
+    each bound to its row's max: one O(D) pass per FW step.
+
+    Every bound already covers its row's unchanged entries, so the ratchet
+    is ``ga_update``'s scatter-max with each touched coordinate's current
+    priority.  Stale-safe: bounds stay upper bounds and never go down here.
+    """
+    p = as_table(priorities, *state.p.shape)
+    bound = jnp.maximum(state.bound, jnp.max(p, axis=1))
     return GroupArgmaxState(p=p, bound=bound, d=state.d)
 
 

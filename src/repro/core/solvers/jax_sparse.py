@@ -11,7 +11,9 @@ This is the paper's fast iteration wired end-to-end through ``kernels/``
                       winning group's tile), or the lazy group-argmax for the
                       non-private queue;
   * lines 22-28     — ``kernels/coord_update``: one coordinate's v̄, q̄, α and
-                      g̃ increment from the selected column's tile.
+                      g̃ increment from the selected column's tile;
+  * line 29 refresh — once per step, a dense rebuild of the queue from the
+                      final α (``tl_rebuild`` / ``ga_rebuild``).
 
 The T-iteration loop is a single ``lax.scan``, so the whole optimization
 lowers to one XLA while-loop with the kernels inlined — jit/pjit-compilable
@@ -57,8 +59,8 @@ import numpy as np
 
 from repro.core.dp.accountant import em_log_weight_scale
 from repro.core.losses import get_loss
-from repro.core.samplers.bsls_jax import tl_init, tl_update
-from repro.core.samplers.group_argmax import ga_get_next, ga_init, ga_update
+from repro.core.samplers.bsls_jax import tl_init, tl_rebuild
+from repro.core.samplers.group_argmax import ga_get_next, ga_init, ga_rebuild
 from repro.core.solvers.config import STOP_MAX_STEPS, FWConfig, FWResult
 from repro.core.solvers.stopping import (assemble_outputs, drive_chunks,
                                          resolve_chunk)
@@ -199,9 +201,8 @@ def fw_scan_chunk(
             key_next, sel_key = jax.random.split(key)
             if private:
                 j = two_level_draw(sampler.c, sampler.v, sel_key)
-                sampler_after_sel = sampler
             else:
-                j, sampler_after_sel = ga_get_next(sampler)
+                j, sampler = ga_get_next(sampler)
             j = jnp.minimum(j, d - 1)
         # ---- lines 16-21 -----------------------------------------------------
         with jax.named_scope("fw.step"):
@@ -215,20 +216,18 @@ def fw_scan_chunk(
             g_tilde = g_tilde * (1.0 - eta) + eta * d_tilde * a_j
         # ---- lines 22-28: the coordinate update over column j's tile -------
         def apply_tile(col):
-            """Lines 22-29 on column j's tile, ``TILE_ROWS`` rows at a
-            time: the coordinate update, then the queue refresh of every
-            coordinate those rows touch.  Only the chunks holding j's nnz
-            rows run, so a step moves O(nnz_j·Kr) lanes rather than the
-            padded width's — the densest column sets the layout's width,
-            not every step's cost.  Rows are distinct, so the chunks' v̄/q̄
-            updates commute; a coordinate touched by several chunks is
-            refreshed after each, the last time with its final α."""
+            """Lines 22-28 on column j's tile, ``TILE_ROWS`` rows at a
+            time.  Only the chunks holding j's nnz rows run, so a step
+            moves O(nnz_j·Kr) lanes rather than the padded width's — the
+            densest column sets the layout's width, not every step's cost.
+            Rows are distinct, so the chunks' v̄/q̄ updates commute.  The
+            queue is refreshed after the loop, once, from the final α."""
             with jax.named_scope("fw.coord_update"):
                 rows, xvals, mask = (_pad_rows(a) for a in col())   # (K,)
                 n_chunks = (jnp.sum(mask) + TILE_ROWS - 1) // TILE_ROWS
 
             def chunk(c, state):
-                vbar, qbar, alpha, g_tilde, sampler = state
+                vbar, qbar, alpha, g_tilde = state
                 with jax.named_scope("fw.coord_update"):
                     r, x, m = (jax.lax.dynamic_slice_in_dim(
                         a, c * TILE_ROWS, TILE_ROWS)
@@ -240,29 +239,28 @@ def fw_scan_chunk(
                         vbar, qbar, alpha, w, r, x, m, row_idx, row_val,
                         eta=eta, d_tilde=d_tilde, w_m=w_m, inv_n=inv_n,
                         loss=loss, y_col=y_col)
-                # line 29: refresh queue priorities for touched coordinates
-                with jax.named_scope("fw.queue_refresh"):
-                    flat_idx = row_idx.reshape(-1)
-                    fresh = jnp.abs(alpha[flat_idx]) * (em_scale if private
-                                                        else 1.0)
-                    refresh = tl_update if private else ga_update
-                    sampler = refresh(sampler, flat_idx, fresh)
-                return vbar, qbar, alpha, g_tilde + g_c, sampler
+                return vbar, qbar, alpha, g_tilde + g_c
 
             return jax.lax.fori_loop(
-                0, n_chunks, chunk,
-                (vbar, qbar, alpha, g_tilde, sampler_after_sel))
+                0, n_chunks, chunk, (vbar, qbar, alpha, g_tilde))
 
         if isinstance(pcsc, TieredCSC):
             # §11 tiered layout: the few heavy columns run the full-width
             # tile, everything else the narrow one — same sums, fewer lanes
-            vbar, qbar, alpha, g_tilde, sampler = jax.lax.cond(
+            vbar, qbar, alpha, g_tilde = jax.lax.cond(
                 pcsc.is_heavy(j),
                 lambda: apply_tile(lambda: pcsc.col_heavy(j)),
                 lambda: apply_tile(lambda: pcsc.col_light(j)))
         else:
-            vbar, qbar, alpha, g_tilde, sampler = apply_tile(
-                lambda: pcsc.col(j))
+            vbar, qbar, alpha, g_tilde = apply_tile(lambda: pcsc.col(j))
+        # ---- line 29: refresh the queue from the step's final α ------------
+        # one dense O(D) pass; untouched coordinates rewrite the value they
+        # hold, so the queue equals a scatter of every touched coordinate
+        with jax.named_scope("fw.queue_refresh"):
+            if private:
+                sampler = tl_rebuild(sampler, jnp.abs(alpha) * em_scale)
+            else:
+                sampler = ga_rebuild(sampler, jnp.abs(alpha))
         new = FWCarry(w, w_m, g_tilde, vbar, qbar, alpha, sampler, key_next,
                       done, stop_at)
         if not early_stop:

@@ -245,10 +245,11 @@ def repack_carry(carry, keep: np.ndarray, em_scale, private: bool):
 
     w/α are sliced; v̄/q̄/g̃ are row-space and — because supp(w) is always
     kept — already equal to the restricted problem's state.  The sampler is
-    *rebuilt* from the live |α| values, which is value-exact: ``tl_update``
-    recomputes every group logsumexp from the value table each step, and the
-    lazy argmax ratchet re-derives its bounds from the same priorities, so
-    both inits reproduce the state the restricted run would hold.
+    *rebuilt* from the live |α| values, which is value-exact: the scan's
+    per-step refresh (``tl_rebuild``) rewrites the whole table from |α| and
+    takes each changed group's logsumexp from it, and the lazy argmax
+    ratchet re-derives its bounds from the same priorities, so both inits
+    reproduce the state the restricted run would hold.
     """
     from repro.core.samplers.bsls_jax import tl_init
     from repro.core.samplers.group_argmax import ga_init
