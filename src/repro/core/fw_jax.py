@@ -25,7 +25,7 @@ from repro.core.dp.accountant import per_step_epsilon
 from repro.core.fw_dense import FWConfig, FWResult
 from repro.core.samplers.bsls_jax import tl_init, tl_sample, tl_update
 from repro.core.samplers.group_argmax import ga_get_next, ga_init, ga_update
-from repro.core.sparse.formats import PaddedCSC, PaddedCSR
+from repro.core.sparse.formats import PaddedCSC, PaddedCSR, refuse_chunked
 
 
 @dataclasses.dataclass(frozen=True)
@@ -36,6 +36,7 @@ class SparseJaxConfig(FWConfig):
 def sparse_fw_jax(
     pcsr: PaddedCSR, pcsc: PaddedCSC, y: jnp.ndarray, config: SparseJaxConfig
 ) -> FWResult:
+    refuse_chunked(pcsc, "the jax_dense backend")
     n, d = pcsr.shape
     lam = config.lam
     loss = config.loss_fn()

@@ -250,7 +250,8 @@ def tune_jax_sparse(pcsr, pcsc, y, *, loss: str = "logistic", steps: int = 24,
     from repro.core.losses import get_loss
     from repro.core.solvers.jax_sparse import em_scale_for, fw_setup_jit
     from repro.core.solvers.planner import data_stats
-    from repro.core.sparse.formats import tiered_from_padded
+    from repro.core.sparse.formats import refuse_chunked, tiered_from_padded
+    refuse_chunked(pcsc, "the tier search (autotune)")
     plat = platform or jax.devices()[0].platform
     y32 = jnp.asarray(y, jnp.float32)
     if setup is None:

@@ -64,7 +64,8 @@ from repro.core.samplers.group_argmax import ga_get_next, ga_init, ga_rebuild
 from repro.core.solvers.config import STOP_MAX_STEPS, FWConfig, FWResult
 from repro.core.solvers.stopping import (assemble_outputs, drive_chunks,
                                          resolve_chunk)
-from repro.core.sparse.formats import PaddedCSC, PaddedCSR, TieredCSC
+from repro.core.sparse.formats import (ChunkedCSC, PaddedCSR, TieredCSC,
+                                       col_layout, refuse_chunked)
 from repro.kernels.bsls_draw.ops import two_level_draw
 from repro.kernels.coord_update.ops import coord_update
 from repro.kernels.spmv.ops import ell_rmatvec
@@ -78,9 +79,18 @@ def step_chunks(col_nnz, coords) -> np.ndarray:
     """Row chunks the coordinate update ran at each step of a fixed-T scan's
     ``coords`` (any shape, e.g. (lanes, T)): ⌈nnz_j / TILE_ROWS⌉ for the
     step's coordinate j.  ``col_nnz`` is the layout's true per-column
-    counts (``nnz`` of the flat and the tiered layout).  Host-side
-    telemetry: it copies both arrays to the host."""
+    counts (``nnz`` of every column layout).  Host-side telemetry: it
+    copies both arrays to the host."""
     return -(-np.asarray(col_nnz)[np.asarray(coords)] // TILE_ROWS)
+
+
+def _scan_counts(col_nnz, coords) -> dict:
+    """``solve.scan``'s deferred work counts: ``chunks`` (``step_chunks``
+    summed) and ``col_nnz``, the sum over steps of nnz_j for the step's
+    column — the column slots of those chunks that hold a real entry."""
+    col_nnz, coords = np.asarray(col_nnz), np.asarray(coords)
+    return {"chunks": int(step_chunks(col_nnz, coords).sum()),
+            "col_nnz": int(col_nnz[coords].astype(np.int64).sum())}
 
 
 def _pad_rows(a: jnp.ndarray) -> jnp.ndarray:
@@ -215,23 +225,18 @@ def fw_scan_chunk(
             w = w.at[j].add(eta * d_tilde / w_m)
             g_tilde = g_tilde * (1.0 - eta) + eta * d_tilde * a_j
         # ---- lines 22-28: the coordinate update over column j's tile -------
-        def apply_tile(col):
+        def apply_tile(n_chunks, fetch):
             """Lines 22-28 on column j's tile, ``TILE_ROWS`` rows at a
-            time.  Only the chunks holding j's nnz rows run, so a step
-            moves O(nnz_j·Kr) lanes rather than the padded width's — the
-            densest column sets the layout's width, not every step's cost.
-            Rows are distinct, so the chunks' v̄/q̄ updates commute.  The
-            queue is refreshed after the loop, once, from the final α."""
-            with jax.named_scope("fw.coord_update"):
-                rows, xvals, mask = (_pad_rows(a) for a in col())   # (K,)
-                n_chunks = (jnp.sum(mask) + TILE_ROWS - 1) // TILE_ROWS
-
+            time: ``fetch(c)`` gives chunk c's (rows, values, mask).  Only
+            the chunks holding j's nnz rows run, so a step moves
+            O(nnz_j·Kr) lanes rather than the padded width's — the densest
+            column sets the layout's width, not every step's cost.  Rows
+            are distinct, so the chunks' v̄/q̄ updates commute.  The queue
+            is refreshed after the loop, once, from the final α."""
             def chunk(c, state):
                 vbar, qbar, alpha, g_tilde = state
                 with jax.named_scope("fw.coord_update"):
-                    r, x, m = (jax.lax.dynamic_slice_in_dim(
-                        a, c * TILE_ROWS, TILE_ROWS)
-                        for a in (rows, xvals, mask))
+                    r, x, m = fetch(c)
                     row_idx = pcsr.indices[r]       # (C, Kr)
                     row_val = pcsr.values[r]        # (C, Kr) — 0 at padding
                     y_col = None if obj.separable else y[r]
@@ -244,15 +249,32 @@ def fw_scan_chunk(
             return jax.lax.fori_loop(
                 0, n_chunks, chunk, (vbar, qbar, alpha, g_tilde))
 
+        def padded_tile(col):
+            """``apply_tile`` on a padded column: the whole (K,) row of
+            the table, sliced into chunks."""
+            with jax.named_scope("fw.coord_update"):
+                rows, xvals, mask = (_pad_rows(a) for a in col())   # (K,)
+                n_chunks = (jnp.sum(mask) + TILE_ROWS - 1) // TILE_ROWS
+            return apply_tile(n_chunks, lambda c: tuple(
+                jax.lax.dynamic_slice_in_dim(a, c * TILE_ROWS, TILE_ROWS)
+                for a in (rows, xvals, mask)))
+
         if isinstance(pcsc, TieredCSC):
             # §11 tiered layout: the few heavy columns run the full-width
             # tile, everything else the narrow one — same sums, fewer lanes
             vbar, qbar, alpha, g_tilde = jax.lax.cond(
                 pcsc.is_heavy(j),
-                lambda: apply_tile(lambda: pcsc.col_heavy(j)),
-                lambda: apply_tile(lambda: pcsc.col_light(j)))
+                lambda: padded_tile(lambda: pcsc.col_heavy(j)),
+                lambda: padded_tile(lambda: pcsc.col_light(j)))
+        elif isinstance(pcsc, ChunkedCSC):
+            # §5 nnz-bounded layout: each chunk is read straight from the
+            # flat entry arrays
+            with jax.named_scope("fw.coord_update"):
+                n_chunks = (jnp.take(pcsc.nnz, j) + TILE_ROWS - 1) // TILE_ROWS
+            vbar, qbar, alpha, g_tilde = apply_tile(
+                n_chunks, lambda c: pcsc.chunk(j, c, TILE_ROWS))
         else:
-            vbar, qbar, alpha, g_tilde = apply_tile(lambda: pcsc.col(j))
+            vbar, qbar, alpha, g_tilde = padded_tile(lambda: pcsc.col(j))
         # ---- line 29: refresh the queue from the step's final α ------------
         # one dense O(D) pass; untouched coordinates rewrite the value they
         # hold, so the queue equals a scatter of every touched coordinate
@@ -382,6 +404,7 @@ def _screened_chunked_fw(pcsr, pcsc, setup, config: FWConfig,
                                               repack_pair)
     from repro.core.solvers.stopping import ChunkGeometry
 
+    refuse_chunked(pcsc, "screening (FWConfig.screen_every)")
     dtype = pcsr.values.dtype
     pad_col = (pcsc.full_width if isinstance(pcsc, TieredCSC)
                else int(pcsc.indices.shape[1]))
@@ -475,7 +498,8 @@ def jax_sparse_fw(
         return _chunked_fw(pcsr, pcsc, setup, config, em_scale, private,
                            y=y_scan)
     vbar0, qbar0, alpha0 = setup
-    with obs.span("solve.scan", steps=config.steps, private=private) as sp:
+    with obs.span("solve.scan", steps=config.steps, private=private,
+                  col_layout=col_layout(pcsc)) as sp:
         t0 = time.perf_counter()
         w, gaps, coords, stop_step = fw_scan_jit(
             pcsr, pcsc, vbar0, qbar0, alpha0,
@@ -487,12 +511,12 @@ def jax_sparse_fw(
         losses = jnp.zeros_like(gaps)
         if obs.enabled():
             # with a collector only: the span ends with the device run;
-            # the row chunks the fit's coordinates ran are counted when
-            # the collector settles, off the measured region
+            # the row chunks the fit's coordinates ran, and the real
+            # entries in them, are counted when the collector settles, off
+            # the measured region
             sp.set(enqueue_s=enqueue_s, tile_rows=TILE_ROWS)
             jax.block_until_ready((w, gaps, coords, losses))
             col_nnz = pcsc.nnz
-            sp.defer(lambda: {
-                "chunks": int(step_chunks(col_nnz, coords).sum())})
+            sp.defer(lambda: _scan_counts(col_nnz, coords))
     return FWResult(w=w, gaps=gaps, coords=coords, losses=losses,
                     stop_step=config.steps, stop_reason=STOP_MAX_STEPS)

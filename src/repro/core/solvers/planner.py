@@ -110,19 +110,24 @@ def store_stats(store) -> ProblemStats:
 def data_stats(X) -> ProblemStats:
     """Derive :class:`ProblemStats` from any layout ``solve`` accepts."""
     from repro.core.solvers.prepared import PreparedDataset
-    from repro.core.sparse.formats import (HostCSR, PaddedCSC, PaddedCSR,
-                                           TieredCSC)
+    from repro.core.sparse.formats import (ChunkedCSC, HostCSR, PaddedCSC,
+                                           PaddedCSR, TieredCSC)
     if isinstance(X, PreparedDataset):
         X = X.pair
     if (isinstance(X, tuple) and len(X) == 2
             and isinstance(X[0], PaddedCSR)
-            and isinstance(X[1], (PaddedCSC, TieredCSC))):
+            and isinstance(X[1], (PaddedCSC, TieredCSC, ChunkedCSC))):
         pcsr, pcsc = X
         n, d = pcsr.shape
         # a tiered CSC's cost-relevant tile height is the true max column
-        # nnz — the full-width heavy tier, not the narrow light table
-        kc = (pcsc.full_width if isinstance(pcsc, TieredCSC)
-              else int(pcsc.indices.shape[1]))
+        # nnz — the full-width heavy tier, not the narrow light table; the
+        # nnz-bounded layout has no width, so its largest column nnz
+        if isinstance(pcsc, TieredCSC):
+            kc = pcsc.full_width
+        elif isinstance(pcsc, ChunkedCSC):
+            kc = max(1, int(np.max(np.asarray(pcsc.nnz), initial=0)))
+        else:
+            kc = int(pcsc.indices.shape[1])
         return ProblemStats(n=n, d=d, nnz=int(np.sum(np.asarray(pcsr.nnz))),
                             kc=kc, kr=int(pcsr.indices.shape[1]))
     if isinstance(X, HostCSR):

@@ -1,9 +1,9 @@
 """Prepared device-resident dataset: padded layouts + cached solver setup.
 
 ``PreparedDataset`` is what the solver registry's padded coercion returns
-for a ``repro.data.store.DatasetStore``: the ``(PaddedCSR, PaddedCSC)`` pair
-plus a memo of the config-independent Frank-Wolfe setup state
-``(v̄₀, q̄₀, α₀)`` per loss — the O(NS) spmv sweep
+for a ``repro.data.store.DatasetStore``: the ``(PaddedCSR, PaddedCSC |
+ChunkedCSC)`` pair plus a memo of the config-independent Frank-Wolfe setup
+state ``(v̄₀, q̄₀, α₀)`` per loss — the O(NS) spmv sweep
 ``jax_sparse.fw_setup`` would otherwise re-run on every solve.
 
 Exactness contract: on a cache miss the setup is computed by the *same*
@@ -20,12 +20,12 @@ The cached setup is keyed to the labels it was computed against: calling
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple, Union
 
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.sparse.formats import PaddedCSC, PaddedCSR
+from repro.core.sparse.formats import ChunkedCSC, PaddedCSC, PaddedCSR
 
 SetupState = Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]  # (v̄₀, q̄₀, α₀)
 SetupLoader = Callable[[str], Optional[SetupState]]
@@ -39,7 +39,7 @@ class PreparedDataset:
     """Padded pair + per-loss setup cache, bound to one label vector."""
 
     pcsr: PaddedCSR
-    pcsc: PaddedCSC
+    pcsc: Union[PaddedCSC, ChunkedCSC]
     y: np.ndarray                         # labels the setup cache is bound to
     loader: Optional[SetupLoader] = None  # disk-cache read hook (store)
     saver: Optional[SetupSaver] = None    # disk-cache write hook (store)
@@ -56,7 +56,7 @@ class PreparedDataset:
         return self.pcsr.shape
 
     @property
-    def pair(self) -> Tuple[PaddedCSR, PaddedCSC]:
+    def pair(self) -> Tuple[PaddedCSR, Union[PaddedCSC, ChunkedCSC]]:
         return self.pcsr, self.pcsc
 
     def _bound_labels(self, y) -> bool:

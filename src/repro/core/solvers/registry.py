@@ -30,8 +30,9 @@ import numpy as np
 from repro.core.solvers.config import (FWConfig, FWResult,
                                        check_gap_certificate)
 from repro.core.solvers.prepared import PreparedDataset
-from repro.core.sparse.formats import (HostCSR, PaddedCSC, PaddedCSR,
-                                       dense_to_host, host_to_padded)
+from repro.core.sparse.formats import (ChunkedCSC, HostCSR, PaddedCSC,
+                                       PaddedCSR, dense_to_host,
+                                       host_to_padded)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -149,8 +150,11 @@ def backend_doc(name: str) -> str:
 
 
 def _is_padded_pair(X) -> bool:
+    """A ``(PaddedCSR, PaddedCSC | ChunkedCSC)`` pair, as ``as_padded``
+    builds it (DESIGN.md §5)."""
     return (isinstance(X, tuple) and len(X) == 2
-            and isinstance(X[0], PaddedCSR) and isinstance(X[1], PaddedCSC))
+            and isinstance(X[0], PaddedCSR)
+            and isinstance(X[1], (PaddedCSC, ChunkedCSC)))
 
 
 def _as_store(X):

@@ -60,7 +60,8 @@ from repro import obs
 from repro.core.dp.accountant import per_step_epsilon
 from repro.core.solvers.config import FWConfig
 from repro.core.sparse.formats import (PaddedCSC, PaddedCSR, TieredCSC,
-                                       lane_padded, tiered_from_padded)
+                                       lane_padded, refuse_chunked,
+                                       tiered_from_padded)
 
 # Survivor floor: never screen below max(DEFAULT_MIN_KEEP, √D₀) coordinates —
 # the later FW iterations need a working set, and √D is the natural group
@@ -162,6 +163,7 @@ def _csc_full_arrays(pcsc) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Full-width numpy (indices, values, nnz) of any CSC layout — the §11
     tiered split is re-flattened (heavy rows overwrite their truncated light
     copies) so the repack sees every entry exactly once."""
+    refuse_chunked(pcsc, "the screening repack")
     if isinstance(pcsc, TieredCSC):
         d = pcsc.indices.shape[0]
         full = pcsc.full_width

@@ -1,4 +1,5 @@
 from repro.core.sparse.formats import (  # noqa: F401
+    ChunkedCSC,
     HostCSC,
     HostCSR,
     PaddedCSC,

@@ -14,6 +14,11 @@ Two families:
   asymptotic nnz-proportional work is preserved (padded nnz, see
   ``padding_overhead``) while every op lowers to gather / segment-sum that the
   VPU executes at line rate.
+
+* ``ChunkedCSC`` — the column side with bytes bounded by nnz: every column's
+  entries back to back in one flat array, read 128 rows at a time.
+  ``host_to_padded`` builds it instead of ``PaddedCSC`` when the flat column
+  table would not fit the device (DESIGN.md §5).
 """
 from __future__ import annotations
 
@@ -248,6 +253,44 @@ class PaddedCSC:
 
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
+class ChunkedCSC:
+    """nnz-bounded CSC: column j's rows are ``indices[indptr[j]:indptr[j+1]]``.
+
+    The entries of all columns lie back to back, followed by one chunk
+    (``LANES``) of tail padding, so a chunk read never runs off the end —
+    ``dynamic_slice`` would otherwise clamp its start and read the wrong
+    entries.  ``chunk(j, c)`` masks the slots past the column's end to
+    ``index = 0, value = 0``, so chunk c of a column equals chunk c of the
+    flat ``PaddedCSC`` table element for element."""
+
+    indptr: jnp.ndarray   # (D + 1,) int32 column starts
+    indices: jnp.ndarray  # (nnz + LANES,) int32 row ids
+    values: jnp.ndarray   # (nnz + LANES,) float
+    nnz: jnp.ndarray      # (D,) int32 true column counts
+    shape: Shape          # static (N, D)
+
+    def tree_flatten(self):
+        return (self.indptr, self.indices, self.values, self.nnz), self.shape
+
+    @classmethod
+    def tree_unflatten(cls, shape, leaves):
+        return cls(*leaves, shape=shape)
+
+    def chunk(self, j, c, rows: int = LANES
+              ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+        """Rows/values/mask of rows ``rows·c … rows·c + rows − 1`` of column
+        j (traced ``j`` and ``c``; ``rows`` at most the tail padding)."""
+        if rows > LANES:
+            raise ValueError(f"a chunk is at most {LANES} rows, got {rows}")
+        start = jnp.take(self.indptr, j) + c * rows
+        idx = jax.lax.dynamic_slice_in_dim(self.indices, start, rows)
+        val = jax.lax.dynamic_slice_in_dim(self.values, start, rows)
+        mask = c * rows + jnp.arange(rows) < jnp.take(self.nnz, j)
+        return (jnp.where(mask, idx, 0), jnp.where(mask, val, 0), mask)
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
 class TieredCSC:
     """Two-tier padded CSC: the autotuner's exact-arithmetic ELL split.
 
@@ -316,6 +359,7 @@ def tiered_from_padded(pcsc: PaddedCSC, width: int) -> TieredCSC:
     table unchanged (their truncated lanes were all padding); wider columns
     keep their full lanes in the heavy table and are dispatched there.
     """
+    refuse_chunked(pcsc, "the tiered layout")
     full = int(pcsc.indices.shape[1])
     width = int(width)
     if not 1 <= width < full:
@@ -356,13 +400,94 @@ def dense_to_padded(x: np.ndarray) -> Tuple[PaddedCSR, PaddedCSC]:
     return host_to_padded(csr)
 
 
-def host_to_padded(csr: HostCSR) -> Tuple[PaddedCSR, PaddedCSC]:
+def col_layout(pcsc) -> str:
+    """Name of a column layout: ``"flat"``, ``"tiered"`` or ``"chunked"``."""
+    if isinstance(pcsc, ChunkedCSC):
+        return "chunked"
+    return "tiered" if isinstance(pcsc, TieredCSC) else "flat"
+
+
+def refuse_chunked(pcsc, who: str) -> None:
+    """TypeError when ``pcsc`` is the nnz-bounded layout, for the paths that
+    read the flat column table (``who`` names the path)."""
+    if isinstance(pcsc, ChunkedCSC):
+        raise TypeError(
+            f"{who} needs the flat PaddedCSC column table and does not take "
+            "the nnz-bounded ChunkedCSC; its flat table would not fit the "
+            "device (DESIGN.md §5) — use the jax_sparse backend")
+
+
+def flat_col_bytes(col_nnz: np.ndarray) -> int:
+    """Device bytes of the flat ``PaddedCSC`` table for these column counts:
+    D × ``lane_padded(max nnz)`` × (4 B row id + 4 B value)."""
+    col_nnz = np.asarray(col_nnz)
+    k = lane_padded(int(col_nnz.max()) if col_nnz.size else 1)
+    return int(col_nnz.shape[0]) * k * 8
+
+
+def choose_col_layout(col_nnz: np.ndarray, row_bytes: int,
+                      device_bytes) -> str:
+    """``"flat"`` when the flat column table fits ``device_bytes`` beside
+    the row table (``row_bytes``), else ``"chunked"``.  ``device_bytes``
+    None (a device that reports no memory, such as the CPU) keeps the flat
+    layout.  Pure: it allocates nothing."""
+    if device_bytes is None:
+        return "flat"
+    fits = flat_col_bytes(col_nnz) + int(row_bytes) <= int(device_bytes)
+    return "flat" if fits else "chunked"
+
+
+def _device_bytes():
+    """Memory of the device the layout goes to, or None if unreported."""
+    stats = jax.devices()[0].memory_stats()
+    return None if not stats else stats.get("bytes_limit")
+
+
+def host_to_chunked(csr: HostCSR) -> ChunkedCSC:
+    """The nnz-bounded column layout of ``csr``, vectorised: a stable sort of
+    the entries by column keeps each column's rows in increasing order, the
+    order ``HostCSR.tocsc`` gives."""
     n, d = csr.shape
-    csc = csr.tocsc()
+    order = np.argsort(csr.indices, kind="stable")
+    counts = np.bincount(csr.indices, minlength=d)
+    indptr = np.zeros(d + 1, np.int64)
+    np.cumsum(counts, out=indptr[1:])
+    rows = np.repeat(np.arange(n, dtype=np.int32), np.diff(csr.indptr))
+    indices = np.zeros(csr.nnz + LANES, np.int32)
+    values = np.zeros(csr.nnz + LANES, np.float32)
+    indices[: csr.nnz] = rows[order]
+    values[: csr.nnz] = csr.data[order]
+    return ChunkedCSC(jnp.asarray(indptr.astype(np.int32)),
+                      jnp.asarray(indices), jnp.asarray(values),
+                      jnp.asarray(counts.astype(np.int32)), (n, d))
+
+
+def col_bytes(pcsc) -> int:
+    """Device bytes of a column layout's arrays."""
+    return int(sum(a.size * a.dtype.itemsize
+                   for a in jax.tree_util.tree_leaves(pcsc)))
+
+
+def host_to_padded(csr: HostCSR) -> Tuple[PaddedCSR, "PaddedCSC | ChunkedCSC"]:
+    """The row table and a column layout chosen from the input: the flat
+    ``PaddedCSC`` when its table fits the device beside the row table
+    (``choose_col_layout``), else the nnz-bounded ``ChunkedCSC``."""
+    from repro import obs
+    n, d = csr.shape
     k_row = int(max(1, np.max(np.diff(csr.indptr)) if csr.nnz else 1))
-    k_col = lane_padded(np.max(np.diff(csc.indptr)) if csc.nnz else 1)
-    ri, rv, rn = _pad_rows(csr.indptr, csr.indices, csr.data, n, k_row)
-    ci, cv, cn = _pad_rows(csc.indptr, csc.indices, csc.data, d, k_col)
-    pcsr = PaddedCSR(jnp.asarray(ri), jnp.asarray(rv), jnp.asarray(rn), (n, d))
-    pcsc = PaddedCSC(jnp.asarray(ci), jnp.asarray(cv), jnp.asarray(cn), (n, d))
+    col_nnz = np.bincount(csr.indices, minlength=d)
+    layout = choose_col_layout(col_nnz, n * k_row * 8, _device_bytes())
+    with obs.span("layout.build", col_layout=layout, nnz=csr.nnz) as sp:
+        ri, rv, rn = _pad_rows(csr.indptr, csr.indices, csr.data, n, k_row)
+        pcsr = PaddedCSR(jnp.asarray(ri), jnp.asarray(rv), jnp.asarray(rn),
+                         (n, d))
+        if layout == "chunked":
+            pcsc = host_to_chunked(csr)
+        else:
+            csc = csr.tocsc()
+            k_col = lane_padded(np.max(np.diff(csc.indptr)) if csc.nnz else 1)
+            ci, cv, cn = _pad_rows(csc.indptr, csc.indices, csc.data, d, k_col)
+            pcsc = PaddedCSC(jnp.asarray(ci), jnp.asarray(cv),
+                             jnp.asarray(cn), (n, d))
+        sp.set(col_bytes=col_bytes(pcsc))
     return pcsr, pcsc

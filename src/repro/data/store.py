@@ -56,6 +56,8 @@ FORMAT_VERSION = 1
 MANIFEST = "manifest.json"
 COLSTATS = "colstats.npz"
 CACHE_DIR = "cache"
+# the arrays of the nnz-bounded column layout in the padded cache
+_CHUNKED_PARTS = ("indptr", "indices", "values", "nnz")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -462,14 +464,17 @@ class DatasetStore:
         return os.path.join(self.root, CACHE_DIR, "padded-meta.json")
 
     def _padded_load(self):
-        """The padded ELL pair straight off mmap, or None on cache miss."""
+        """The padded pair straight off mmap, or None on cache miss.  The
+        meta names the column layout (``col_layout``); a cache written
+        before it did holds the flat one."""
         meta_path = self._padded_meta_path()
         if not os.path.exists(meta_path):
             _cache_count("padded", hit=False)
             return None
         with open(meta_path) as f:
             meta = json.load(f)
-        from repro.core.sparse.formats import LANES, PaddedCSC, PaddedCSR
+        from repro.core.sparse.formats import (LANES, ChunkedCSC, PaddedCSC,
+                                               PaddedCSR)
         if (meta.get("content_hash") != self.content_hash
                 or meta.get("lanes") != LANES):
             _cache_count("padded", hit=False)
@@ -477,25 +482,33 @@ class DatasetStore:
         _cache_count("padded", hit=True)
         import jax.numpy as jnp
 
-        def arrays(kind):
+        def arrays(kind, parts=("indices", "values", "nnz")):
             base = os.path.join(self.root, CACHE_DIR, f"padded-{kind}")
             return tuple(jnp.asarray(
                 np.load(f"{base}.{part}.npy", mmap_mode="r"))
-                for part in ("indices", "values", "nnz"))
+                for part in parts)
 
-        return (PaddedCSR(*arrays("csr"), shape=self.shape),
-                PaddedCSC(*arrays("csc"), shape=self.shape))
+        if meta.get("col_layout", "flat") == "chunked":
+            pcsc = ChunkedCSC(*arrays("csc", _CHUNKED_PARTS),
+                              shape=self.shape)
+        else:
+            pcsc = PaddedCSC(*arrays("csc"), shape=self.shape)
+        return PaddedCSR(*arrays("csr"), shape=self.shape), pcsc
 
     def _padded_save(self, pcsr, pcsc) -> None:
+        from repro.core.sparse.formats import LANES, col_layout
+        layout = col_layout(pcsc)
         os.makedirs(os.path.join(self.root, CACHE_DIR), exist_ok=True)
-        for kind, p in (("csr", pcsr), ("csc", pcsc)):
+        csc_parts = (_CHUNKED_PARTS if layout == "chunked"
+                     else ("indices", "values", "nnz"))
+        for kind, p, parts in (("csr", pcsr, ("indices", "values", "nnz")),
+                               ("csc", pcsc, csc_parts)):
             base = os.path.join(self.root, CACHE_DIR, f"padded-{kind}")
-            np.save(f"{base}.indices.npy", np.asarray(p.indices))
-            np.save(f"{base}.values.npy", np.asarray(p.values))
-            np.save(f"{base}.nnz.npy", np.asarray(p.nnz))
-        from repro.core.sparse.formats import LANES
+            for part in parts:
+                np.save(f"{base}.{part}.npy", np.asarray(getattr(p, part)))
         with open(self._padded_meta_path(), "w") as f:
-            json.dump({"content_hash": self.content_hash, "lanes": LANES}, f)
+            json.dump({"content_hash": self.content_hash, "lanes": LANES,
+                       "col_layout": layout}, f)
 
     def _blocks_meta_path(self, a: int, b: int) -> str:
         return os.path.join(self.root, CACHE_DIR, f"blocks-{a}x{b}-meta.json")

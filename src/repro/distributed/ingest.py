@@ -84,6 +84,9 @@ class ShardSource:
         if isinstance(X, DatasetStore):
             return cls(shape=X.shape, store=X)
         from repro.core.solvers.registry import as_host_csr
+        from repro.core.sparse.formats import refuse_chunked
+        if isinstance(X, tuple) and len(X) == 2:
+            refuse_chunked(X[1], "the jax_shard backend")
         csr = as_host_csr(X)
         return cls(shape=csr.shape, csr=csr)
 

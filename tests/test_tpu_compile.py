@@ -107,6 +107,33 @@ def test_scan_chunk_compiles_and_fits(pair, one_chip, private):
     assert ("tpu_custom_call" in compiled.as_text()) == private
 
 
+@pytest.mark.parametrize("private", [False, True])
+def test_news20_chunked_scan_compiles_and_fits(one_chip, private):
+    """news20 at its published shape on the nnz-bounded column layout
+    (DESIGN.md §5): the whole fixed-T scan fits one chip in well under a
+    GB, where the flat column table alone would take 218 GB."""
+    from repro.core.solvers.jax_sparse import fw_scan_jit
+    from repro.core.sparse.formats import ChunkedCSC
+    news20 = DATASETS["news20"]
+    n, d = news20.n, news20.d
+    kr, nnz = 548, 9_095_385     # the generated twin's widest row, its nnz
+    s = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pcsr = PaddedCSR(s((n, kr), jnp.int32), s((n, kr), jnp.float32),
+                     s((n,), jnp.int32), (n, d))
+    pcsc = ChunkedCSC(s((d + 1,), jnp.int32), s((nnz + 128,), jnp.int32),
+                      s((nnz + 128,), jnp.float32), s((d,), jnp.int32),
+                      (n, d))
+    f32 = s((), jnp.float32)
+    key = _abs(jax.eval_shape(lambda: jax.random.PRNGKey(0)), one_chip)
+    compiled = fw_scan_jit.lower(
+        pcsr, pcsc, s((n,), jnp.float32), s((n,), jnp.float32),
+        s((d,), jnp.float32), f32, f32, key, f32, None, steps=4000,
+        loss="logistic", private=private).compile()
+    m = compiled.memory_analysis()
+    assert m.argument_size_in_bytes + m.temp_size_in_bytes < 2**30
+    assert ("tpu_custom_call" in compiled.as_text()) == private
+
+
 def test_jax_shard_scan_compiles_on_2x2(topo):
     """The four-chip path: the sharded scan on a v5e 2×2 mesh, each device
     holding one (N/2 × D/2) block."""
